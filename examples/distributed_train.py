@@ -22,6 +22,7 @@ from repro.configs import get
 from repro.configs.base import ShapeSpec
 from repro.data.pipeline import SyntheticLM
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.optim import adamw
 from repro.sharding import rules
@@ -29,6 +30,7 @@ from repro.sharding import rules
 
 def main():
     assert len(jax.devices()) >= 4, "set XLA_FLAGS device count first"
+    enable_compile_cache()
     mesh = make_debug_mesh((2, 2), ("data", "model"))
     shape = ShapeSpec("tiny", seq_len=64, global_batch=8, kind="train")
 

@@ -38,6 +38,7 @@ import jax
 import numpy as np
 
 from repro.configs import get
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as lm
 from repro.serve.loop import Request, Server
 from repro.serve.offload import DecodeOffload
@@ -69,6 +70,7 @@ def main():
                          "through the virtual-time TrafficServer and "
                          "print disaggregated vs colocated goodput")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get("qwen3-1.7b").reduced().replace(n_layers=4, d_model=256,
                                               d_ff=512, vocab_size=1024)
@@ -97,8 +99,10 @@ def main():
 
     toks = sum(len(r.out_tokens) for r in done)
     lat = [r.finished_at - r.submitted_at for r in done]
+    dev = jax.devices()[0]
     print(f"served {len(done)} requests / {toks} tokens in {wall:.2f}s "
-          f"({toks / wall:.1f} tok/s on CPU, slots={args.slots})")
+          f"({toks / wall:.1f} tok/s on {dev.platform}:{dev.device_kind} "
+          f"x{len(jax.devices())}, compiles included, slots={args.slots})")
     unit = "wall" if args.wall else "virtual"
     print(f"latency ({unit} seconds) p50={np.percentile(lat, 50):.4f}s "
           f"p99={np.percentile(lat, 99):.4f}s")

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import SHAPES, get
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as lm
 from repro.optim import adamw
 from repro.train.loop import LoopConfig, TrainLoop
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--out", default="runs/train_lm")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = build_cfg()
     params = lm.init(cfg, jax.random.PRNGKey(0))

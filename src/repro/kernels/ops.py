@@ -1,77 +1,40 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Dispatch between the Pallas kernels and their XLA twins.
 
-On this container the TPU kernels execute via ``interpret=True`` (the body
-runs on CPU); on a real TPU the same calls compile to Mosaic.  The
-``KernelMode`` switch is what the model stack's MatmulBackend consults:
+The model stack's :class:`~repro.models.layers.Backend` consults these:
 
-  * ``xla``       — plain jnp ops (used for the 512-device dry-run lowering)
-  * ``pallas``    — pallas_call, interpret on CPU / compiled on TPU
+  * ``use_pallas=False`` — plain jnp ops (also what the dry-run lowers)
+  * ``use_pallas=True``  — ``pallas_call``, compiled to Mosaic for the TPU
+
+``interpret=True`` runs a Pallas kernel body through the interpreter
+instead of compiling it.  Nothing here picks it from the device: callers
+that rehearse on the CPU (tests, tiny smoke runs) ask for it explicitly.
 """
 from __future__ import annotations
 
-import functools
-import os
-from typing import Optional
-
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.ame_gemm import ame_gemm
-from repro.kernels.attention import flash_attention
-from repro.kernels.elementwise import ame_elementwise
-from repro.kernels.ssd_scan import ssd_scan
-
-_ON_TPU = jax.default_backend() == "tpu"
-
-
-def _interpret() -> bool:
-    return not _ON_TPU
+from repro.kernels.ssd_scan import ssd_chunked_jnp4, ssd_scan
 
 
 def gemm(a: jnp.ndarray, b: jnp.ndarray, *, use_pallas: bool = False,
-         out_dtype=None, **blocks) -> jnp.ndarray:
+         interpret: bool = False, out_dtype=None, **blocks) -> jnp.ndarray:
     """C = A @ B via the reduction-free output-stationary kernel or XLA."""
     if use_pallas:
-        return ame_gemm(a, b, out_dtype=out_dtype, interpret=_interpret(),
+        return ame_gemm(a, b, out_dtype=out_dtype, interpret=interpret,
                         **blocks)
     return ref.gemm(a, b, out_dtype=out_dtype)
 
 
-def elementwise(kind: str, a: jnp.ndarray, b: jnp.ndarray, *,
-                relu: bool = False, use_pallas: bool = False) -> jnp.ndarray:
-    if use_pallas:
-        return ame_elementwise(a, b, kind=kind, relu=relu,
-                               interpret=_interpret())
-    return ref.elementwise(kind, a, b, relu=relu)
-
-
-def ssd(x, log_a, b, c, *, use_pallas: bool = False, chunk: int = 128):
-    """Batched Mamba2 SSD scan (chunked in both paths — the sequential
-    recurrence lives only in ref.py as the oracle)."""
-    if use_pallas:
-        return ssd_scan(x, log_a, b, c, chunk=chunk, interpret=_interpret())
-    from repro.kernels.ssd_scan import ssd_chunked_jnp
-    return ssd_chunked_jnp(x, log_a, b, c, chunk=chunk)
-
-
-def ssd4(x, log_a, b, c, *, use_pallas: bool = False, chunk: int = 128):
+def ssd4(x, log_a, b, c, *, use_pallas: bool = False,
+         interpret: bool = False, chunk: int = 128):
     """4-D SSD: x (B,H,T,P) — heads stay a shardable axis ('model')."""
     if use_pallas:
         bsz, h, t, p = x.shape
         y = ssd_scan(x.reshape(bsz * h, t, p),
                      log_a.reshape(bsz * h, t),
                      b.reshape(bsz * h, t, -1), c.reshape(bsz * h, t, -1),
-                     chunk=chunk, interpret=_interpret())
+                     chunk=chunk, interpret=interpret)
         return y.reshape(bsz, h, t, p)
-    from repro.kernels.ssd_scan import ssd_chunked_jnp4
     return ssd_chunked_jnp4(x, log_a, b, c, chunk=chunk)
-
-
-def attention(q, k, v, *, causal: bool = True, window: int = 0,
-              use_pallas: bool = False, **blocks):
-    if use_pallas:
-        return flash_attention(q, k, v, causal=causal, window=window,
-                               interpret=_interpret(), **blocks)
-    return jax.vmap(functools.partial(ref.attention, causal=causal,
-                                      window=window))(q, k, v)

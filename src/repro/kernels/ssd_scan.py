@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 DEFAULT_CHUNK = 128
 NEG = -1e30
 
@@ -34,28 +32,36 @@ def _ssd_kernel(x_ref, la_ref, b_ref, c_ref, y_ref, state_ref):
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0].astype(jnp.float32)          # (L, P)
-    la = la_ref[0].astype(jnp.float32)        # (L,)  log decay (<= 0)
+    la = la_ref[0].astype(jnp.float32)        # (1, L) log decay (<= 0)
     b = b_ref[0].astype(jnp.float32)          # (L, N)
     c = c_ref[0].astype(jnp.float32)          # (L, N)
     l = x.shape[0]
+    ti = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
+    causal = si <= ti
 
-    cum = jnp.cumsum(la)                      # inclusive log-decay prefix
+    # inclusive log-decay prefix, as a column and as a row.  Mosaic has no
+    # cumsum and no 1-D vectors, so both come from masked 2-D reductions
+    cum_col = jnp.sum(jnp.where(causal, jnp.broadcast_to(la, (l, l)), 0.0),
+                      axis=1, keepdims=True)                    # (L, 1)
+    cum_row = jnp.sum(jnp.where(si == ti, jnp.broadcast_to(cum_col, (l, l)),
+                                0.0), axis=0, keepdims=True)    # (1, L)
+    total = jnp.sum(la, axis=1, keepdims=True)                  # (1, 1)
     s_in = state_ref[...]                     # (N, P) resident state
 
     # inter-chunk: queries against the carried state
-    y = (c * jnp.exp(cum)[:, None]) @ s_in
+    y = jnp.dot(c * jnp.exp(cum_col), s_in)
 
     # intra-chunk: causal decay-masked score block (state-space duality)
-    ti = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
-    diff = jnp.where(si <= ti, cum[:, None] - cum[None, :], NEG)
-    g = (c @ b.T) * jnp.exp(diff)
-    y += g @ x
+    diff = jnp.where(causal, cum_col - cum_row, NEG)
+    cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())))    # C @ B^T
+    y += jnp.dot(cb * jnp.exp(diff), x)
     y_ref[0] = y.astype(y_ref.dtype)
 
     # state update: decayed carry + outer-product accumulation of the chunk
-    state_ref[...] = (jnp.exp(cum[-1]) * s_in
-                      + (b * jnp.exp(cum[-1] - cum)[:, None]).T @ x)
+    wb = b * jnp.exp(total - cum_col)                           # (L, N)
+    state_ref[...] = (jnp.exp(total) * s_in
+                      + jax.lax.dot_general(wb, x, (((0,), (0,)), ((), ()))))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -166,20 +172,24 @@ def ssd_scan(x: jnp.ndarray, log_a: jnp.ndarray, b: jnp.ndarray,
         b = jnp.pad(b, ((0, 0), (0, pad), (0, 0)))
         c = jnp.pad(c, ((0, 0), (0, pad), (0, 0)))
     tt = t + pad
+    # log_a rides as (BH, 1, T): a (1, 1, L) block keeps its last two dims
+    # tiling-legal (the size-1 dim spans the whole axis), where a (1, L)
+    # block of the 2-D array would not
+    log_a = log_a.reshape(bh, 1, tt)
 
     out = pl.pallas_call(
         _ssd_kernel,
         grid=(bh, tt // lc),
         in_specs=[
             pl.BlockSpec((1, lc, p), lambda i, tchunk: (i, tchunk, 0)),
-            pl.BlockSpec((1, lc), lambda i, tchunk: (i, tchunk)),
+            pl.BlockSpec((1, 1, lc), lambda i, tchunk: (i, 0, tchunk)),
             pl.BlockSpec((1, lc, n), lambda i, tchunk: (i, tchunk, 0)),
             pl.BlockSpec((1, lc, n), lambda i, tchunk: (i, tchunk, 0)),
         ],
         out_specs=pl.BlockSpec((1, lc, p), lambda i, tchunk: (i, tchunk, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, tt, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

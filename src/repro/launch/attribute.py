@@ -1,6 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS_EXTRA", ""))
+os.environ["JAX_PLATFORMS"] = "cpu"     # 512 host devices, never the chip
 """Profiling-by-static-analysis: attribute a cell's roofline terms to
 instructions (the dry-run 'profiler' — there is no wall clock on CPU).
 

@@ -1,8 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS_EXTRA", ""))
-# The two lines above MUST run before any jax import: jax locks the device
-# count at first init.  Everything below is ordinary code.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any jax import: jax locks the device
+# count at first init.  The dry run lowers for 512 host CPU devices and
+# never takes an attached accelerator.  Everything below is ordinary code.
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this proves the distribution config is coherent (sharding
@@ -13,6 +15,9 @@ partitioned HLO.
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-1.7b \
       --shape train_4k --mesh single
   PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
+
+Records go to ``results/dryrun/<arch>.<shape>.<mesh>.json``; ``--out`` writes
+a single cell's record elsewhere.
 """
 import argparse
 import gc
@@ -111,6 +116,9 @@ def main(argv=None):
     ap.add_argument("--tp-mode", default=None,
                     choices=[None, "allreduce", "allgather", "ame_pim"])
     ap.add_argument("--force", action="store_true")
+    ap.add_argument("--out", type=Path, default=None,
+                    help="write the record of one cell (one arch, one "
+                         "shape, one mesh) to this path")
     args = ap.parse_args(argv)
 
     RESULTS_DIR.mkdir(parents=True, exist_ok=True)
@@ -119,11 +127,13 @@ def main(argv=None):
         cells = [(a, s) for a in all_names() for s in SHAPES]
     else:
         cells = [(args.arch, args.shape)]
+    if args.out is not None and (args.all or len(meshes) > 1):
+        ap.error("--out takes the record of one cell")
 
     failures = 0
     for arch, shape in cells:
         for mk in meshes:
-            out = cell_path(arch, shape, mk, args.tp_mode)
+            out = args.out or cell_path(arch, shape, mk, args.tp_mode)
             if out.exists() and not args.force:
                 rec = json.loads(out.read_text())
                 status = ("SKIP " + rec.get("skipped", "")) if "skipped" in rec \

@@ -10,6 +10,15 @@ jax device state (the dry-run sets XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices):
+    """A mesh whose axes are all Auto: the model code places tensors with
+    ``with_sharding_constraint`` (sharding/context.py), which jax refuses on
+    the Explicit axes ``jax.make_mesh`` makes by default."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,7 +33,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "run under launch/dryrun.py (sets "
             "--xla_force_host_platform_device_count)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
@@ -33,4 +42,4 @@ def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _auto_mesh(shape, axes, jax.devices()[:n])

@@ -16,16 +16,20 @@ from repro.sharding.context import constrain
 @dataclasses.dataclass(frozen=True)
 class Backend:
     """Routes dense compute: 'xla' (einsum; used for dry-run lowering) or
-    'pallas' (the AME output-stationary kernels, interpret on CPU)."""
+    'pallas' (the AME output-stationary kernels, compiled for the TPU).
+
+    ``interpret=True`` runs the Pallas kernels through the interpreter, for
+    CPU tests and rehearsals; it is never chosen from the device."""
 
     mode: str = "xla"
+    interpret: bool = False
 
     def matmul(self, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
         """(..., K) @ (K, N) with f32 accumulation."""
         if self.mode == "pallas":
             lead = x.shape[:-1]
             x2 = x.reshape(-1, x.shape[-1])
-            return ops.gemm(x2, w, use_pallas=True,
+            return ops.gemm(x2, w, use_pallas=True, interpret=self.interpret,
                             out_dtype=x.dtype).reshape(*lead, w.shape[-1])
         return jnp.matmul(x, w, preferred_element_type=jnp.float32
                           ).astype(x.dtype)

@@ -113,7 +113,8 @@ def mamba_apply(p, u, cfg: ArchConfig, *, state: Optional[Dict] = None,
         c4 = constrain(jnp.repeat(cg, rep, 2).transpose(0, 2, 1, 3),
                        "batch", "model", None, None)
         y = ops.ssd4(x4, la4.astype(jnp.float32), b4, c4,
-                     use_pallas=(backend.mode == "pallas"), chunk=s.chunk)
+                     use_pallas=(backend.mode == "pallas"),
+                     interpret=backend.interpret, chunk=s.chunk)
         y = constrain(y, "batch", "model", None, None)
         y = y.transpose(0, 2, 1, 3)                            # (B,T,H,P)
         if state is not None:

@@ -28,12 +28,13 @@ def test_distributed_train_example_4dev():
 
 
 @pytest.mark.slow
-def test_dryrun_cell_multi_pod():
+def test_dryrun_cell_multi_pod(tmp_path):
     """One full-config cell lowers+compiles on the 512-chip multi-pod mesh
     (the dry-run path end to end, including the roofline extraction)."""
-    out = ROOT / "results" / "dryrun" / "qwen3-1.7b.decode_32k.multi.json"
+    out = tmp_path / "qwen3-1.7b.decode_32k.multi.json"
     r = run(["-m", "repro.launch.dryrun", "--arch", "qwen3-1.7b",
-             "--shape", "decode_32k", "--mesh", "multi", "--force"])
+             "--shape", "decode_32k", "--mesh", "multi", "--force",
+             "--out", str(out)])
     assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-1500:]
     rec = json.loads(out.read_text())
     assert rec["ok"] and rec["flops"] > 0

@@ -9,7 +9,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.configs import get
 from repro.models import model as lm
-from repro.models.layers import PALLAS, XLA
+from repro.models.layers import XLA, Backend
 
 RNG = np.random.default_rng(11)
 
@@ -84,7 +84,8 @@ def test_pallas_backend_matches_xla(name):
         "loss_mask": jnp.ones((2, 16), jnp.float32),
     }
     l_xla, _ = lm.loss_fn(params, batch, cfg, backend=XLA)
-    l_pal, _ = lm.loss_fn(params, batch, cfg, backend=PALLAS)
+    l_pal, _ = lm.loss_fn(params, batch, cfg,
+                          backend=Backend("pallas", interpret=True))
     assert abs(float(l_xla) - float(l_pal)) < 5e-3, (float(l_xla),
                                                      float(l_pal))
 

@@ -207,12 +207,13 @@ def attention_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
             slot = jnp.zeros((b,), jnp.int32)
         else:
             slot = jnp.zeros((b,), jnp.int32)
-        newk = jax.lax.dynamic_update_slice(cache["k"], kk.astype(cache["k"].dtype),
-                                            (0, 0, 0, 0))
-        newv = jax.lax.dynamic_update_slice(cache["v"], vv.astype(cache["v"].dtype),
-                                            (0, 0, 0, 0))
-        npos = jax.lax.dynamic_update_slice(
-            cache["pos"], pp.astype(jnp.int32), (0, 0))
+        with jax.named_scope("kv_write"):
+            newk = jax.lax.dynamic_update_slice(
+                cache["k"], kk.astype(cache["k"].dtype), (0, 0, 0, 0))
+            newv = jax.lax.dynamic_update_slice(
+                cache["v"], vv.astype(cache["v"].dtype), (0, 0, 0, 0))
+            npos = jax.lax.dynamic_update_slice(
+                cache["pos"], pp.astype(jnp.int32), (0, 0))
         new_cache = {"k": newk, "v": newv, "pos": npos}
         out = chunked_attention(q, k, v, causal=causal,
                                 window=cfg.sliding_window, chunk=chunk,
@@ -227,9 +228,12 @@ def attention_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
         pos = positions[:, 0] if positions.ndim > 1 else positions  # (B,)
         slot = (pos % clen) if cfg.sliding_window else pos
         bi = jnp.arange(b)
-        newk = cache["k"].at[bi, slot].set(k[:, 0].astype(cache["k"].dtype))
-        newv = cache["v"].at[bi, slot].set(v[:, 0].astype(cache["v"].dtype))
-        npos = cache["pos"].at[bi, slot].set(pos.astype(jnp.int32))
+        with jax.named_scope("kv_write"):
+            newk = cache["k"].at[bi, slot].set(
+                k[:, 0].astype(cache["k"].dtype))
+            newv = cache["v"].at[bi, slot].set(
+                v[:, 0].astype(cache["v"].dtype))
+            npos = cache["pos"].at[bi, slot].set(pos.astype(jnp.int32))
         new_cache = {"k": newk, "v": newv, "pos": npos}
         kv_valid = jnp.minimum(pos + 1, clen)
         if heads_shardable:
@@ -308,19 +312,24 @@ def mla_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
     if cache is not None and t == 1:
         pos = positions[:, 0] if positions.ndim > 1 else positions
         bi = jnp.arange(b)
-        ckv_c = cache["ckv"].at[bi, pos].set(ckv[:, 0].astype(cache["ckv"].dtype))
-        kr_c = cache["kr"].at[bi, pos].set(kr[:, 0].astype(cache["kr"].dtype))
+        with jax.named_scope("kv_write"):
+            ckv_c = cache["ckv"].at[bi, pos].set(
+                ckv[:, 0].astype(cache["ckv"].dtype))
+            kr_c = cache["kr"].at[bi, pos].set(
+                kr[:, 0].astype(cache["kr"].dtype))
         new_cache = {"ckv": ckv_c, "kr": kr_c}
         ckv_all, kr_all = ckv_c, kr_c
     else:
         ckv_all, kr_all = ckv, kr
         if cache is not None:  # prefill fills the cache
-            new_cache = {
-                "ckv": jax.lax.dynamic_update_slice(
-                    cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, 0, 0)),
-                "kr": jax.lax.dynamic_update_slice(
-                    cache["kr"], kr.astype(cache["kr"].dtype), (0, 0, 0)),
-            }
+            with jax.named_scope("kv_write"):
+                new_cache = {
+                    "ckv": jax.lax.dynamic_update_slice(
+                        cache["ckv"], ckv.astype(cache["ckv"].dtype),
+                        (0, 0, 0)),
+                    "kr": jax.lax.dynamic_update_slice(
+                        cache["kr"], kr.astype(cache["kr"].dtype), (0, 0, 0)),
+                }
 
     # absorbed form: fold W_uk into q, attend directly against the latent —
     # the compressed cache is both k and v (reduction-free: no per-head KV

@@ -69,6 +69,11 @@ def _head_weight(p, cfg: ArchConfig, dtype):
 
 def _embed_inputs(p, batch: Dict, cfg: ArchConfig):
     """Returns (h0 (B,T,d), positions (B,T), text_offset)."""
+    with jax.named_scope("embed"):
+        return _embed(p, batch, cfg)
+
+
+def _embed(p, batch: Dict, cfg: ArchConfig):
     cd = cfg.compute_dtype_()
     if cfg.modality == "audio_frames":
         h = batch["frames"].astype(cd)
@@ -135,8 +140,14 @@ def loss_fn(params, batch: Dict, cfg: ArchConfig,
     causal = not cfg.encoder_only
     h, _, aux = stack_apply(params["stack"], h, cfg, positions=positions,
                             caches=None, backend=backend, causal=causal)
-    h = apply_norm(params["final_norm"], h, cfg.norm_eps)
+    with jax.named_scope("head"):
+        return _head_loss(params, h, aux, off, batch, cfg, backend)
 
+
+def _head_loss(params, h, aux, off, batch: Dict, cfg: ArchConfig,
+               backend: Backend):
+    """Final norm, LM head and cross-entropy (plus MTP) of ``loss_fn``."""
+    h = apply_norm(params["final_norm"], h, cfg.norm_eps)
     cd = cfg.compute_dtype_()
     head_w = _head_weight(params, cfg, cd)
 
@@ -197,12 +208,19 @@ def prefill(params, batch: Dict, cfg: ArchConfig, cache_len: int,
     h, caches, _ = stack_apply(params["stack"], h, cfg, positions=positions,
                                caches=caches, backend=backend, causal=causal,
                                remat=False)
-    h = apply_norm(params["final_norm"], h, cfg.norm_eps)
-    cd = cfg.compute_dtype_()
-    logits = (h[:, -1] @ _head_weight(params, cfg, cd)).astype(jnp.float32)
-    logits = jnp.where(jnp.arange(cfg.vocab_padded) < cfg.vocab_size,
-                       logits, -1e30)
-    return logits, caches
+    return _logits(params, h, cfg), caches
+
+
+def _logits(params, h, cfg: ArchConfig):
+    """Final norm, LM head and vocabulary mask of the last position:
+    (B,T,d) -> (B,Vp) float32."""
+    with jax.named_scope("head"):
+        h = apply_norm(params["final_norm"], h, cfg.norm_eps)
+        cd = cfg.compute_dtype_()
+        logits = (h[:, -1] @ _head_weight(params, cfg, cd)).astype(
+            jnp.float32)
+        return jnp.where(jnp.arange(cfg.vocab_padded) < cfg.vocab_size,
+                         logits, -1e30)
 
 
 def decode_step(params, tokens, positions, caches, cfg: ArchConfig,
@@ -210,18 +228,15 @@ def decode_step(params, tokens, positions, caches, cfg: ArchConfig,
     """One token per sequence.  tokens (B,1) int32, positions (B,) int32."""
     _, stack_apply = _family_fns(cfg)
     cd = cfg.compute_dtype_()
-    h = params["embed"]["table"].astype(cd)[tokens]          # (B,1,d)
     if cfg.pos_embed == "sinusoidal":
         raise NotImplementedError("encoder-only archs have no decode step")
+    with jax.named_scope("embed"):
+        h = params["embed"]["table"].astype(cd)[tokens]      # (B,1,d)
     pos2 = positions[:, None]
     h, caches, _ = stack_apply(params["stack"], h, cfg, positions=pos2,
                                caches=caches, backend=backend, causal=True,
                                remat=False)
-    h = apply_norm(params["final_norm"], h, cfg.norm_eps)
-    logits = (h[:, 0] @ _head_weight(params, cfg, cd)).astype(jnp.float32)
-    logits = jnp.where(jnp.arange(cfg.vocab_padded) < cfg.vocab_size,
-                       logits, -1e30)
-    return logits, caches
+    return _logits(params, h, cfg), caches
 
 
 def param_count(params) -> int:

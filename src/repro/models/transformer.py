@@ -68,24 +68,28 @@ def block_init(key, cfg: ArchConfig, dtype, use_moe: bool):
 
 def block_apply(p, h, cfg: ArchConfig, *, positions, cache=None,
                 backend: Backend = XLA, causal=True):
-    x = apply_norm(p["ln1"], h, cfg.norm_eps)
-    if cfg.mla is not None:
-        a, new_cache = attn_mod.mla_apply(p["attn"], x, cfg,
-                                          positions=positions, cache=cache,
-                                          backend=backend)
-    else:
-        a, new_cache = attn_mod.attention_apply(
-            p["attn"], x, cfg, positions=positions, cache=cache,
-            backend=backend, causal=causal)
-    a = jax.ad_checkpoint.checkpoint_name(a, "blk_out")
-    h = h + a
-    x = apply_norm(p["ln2"], h, cfg.norm_eps)
-    if "moe" in p:
-        y, aux = moe_mod.moe_apply(p["moe"], x, cfg, backend)
-    else:
-        y, aux = mlp(p["mlp"], x, cfg.act, backend,
-                     policy=cfg.policy), jnp.float32(0)
-    h = h + jax.ad_checkpoint.checkpoint_name(y, "blk_out")
+    # named scopes: each operation's op_name says which half of the block
+    # it belongs to, in the compiled program and in a profiler trace
+    with jax.named_scope("attn"):
+        x = apply_norm(p["ln1"], h, cfg.norm_eps)
+        if cfg.mla is not None:
+            a, new_cache = attn_mod.mla_apply(p["attn"], x, cfg,
+                                              positions=positions,
+                                              cache=cache, backend=backend)
+        else:
+            a, new_cache = attn_mod.attention_apply(
+                p["attn"], x, cfg, positions=positions, cache=cache,
+                backend=backend, causal=causal)
+        a = jax.ad_checkpoint.checkpoint_name(a, "blk_out")
+        h = h + a
+    with jax.named_scope("moe" if "moe" in p else "mlp"):
+        x = apply_norm(p["ln2"], h, cfg.norm_eps)
+        if "moe" in p:
+            y, aux = moe_mod.moe_apply(p["moe"], x, cfg, backend)
+        else:
+            y, aux = mlp(p["mlp"], x, cfg.act, backend,
+                         policy=cfg.policy), jnp.float32(0)
+        h = h + jax.ad_checkpoint.checkpoint_name(y, "blk_out")
     if cfg.policy.sp and h.shape[1] > 1:
         # sequence-parallel residual stream: the per-layer saved residual
         # stack shards its seq dim over 'model' (Megatron-SP posture); XLA
@@ -112,7 +116,8 @@ def _scan_blocks(params_stack, h, cfg, *, positions, caches, backend, causal,
     if remat:
         body = jax.checkpoint(body, prevent_cse=False,
                               policy=_remat_policy(cfg))
-    h, (new_caches, auxs) = jax.lax.scan(body, h, (params_stack, caches))
+    with jax.named_scope("stack"):
+        h, (new_caches, auxs) = jax.lax.scan(body, h, (params_stack, caches))
     return h, new_caches, jnp.sum(auxs)
 
 
@@ -192,15 +197,17 @@ def ssm_stack_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
 
     def body(carry, xs):
         lp, st = xs
-        x = apply_norm(lp["ln"], carry, cfg.norm_eps)
-        y, ns = ssm_mod.mamba_apply(lp["mamba"], x, cfg, state=st,
-                                    backend=backend)
-        return carry + y, ns
+        with jax.named_scope("ssm"):
+            x = apply_norm(lp["ln"], carry, cfg.norm_eps)
+            y, ns = ssm_mod.mamba_apply(lp["mamba"], x, cfg, state=st,
+                                        backend=backend)
+            return carry + y, ns
 
     if remat and caches is None:
         body = jax.checkpoint(body, prevent_cse=False)
     cs = caches["ssm_stack"] if caches else None
-    h, ns = jax.lax.scan(body, h, (p["ssm_stack"], cs))
+    with jax.named_scope("stack"):
+        h, ns = jax.lax.scan(body, h, (p["ssm_stack"], cs))
     return h, ({"ssm_stack": ns} if caches is not None else None), jnp.float32(0)
 
 
@@ -276,20 +283,23 @@ def hybrid_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
 
     def mamba_body(carry, xs):
         lp, st = xs
-        x = apply_norm(lp["ln"], carry, cfg.norm_eps)
-        y, ns = ssm_mod.mamba_apply(lp["mamba"], x, cfg, state=st,
-                                    backend=backend)
-        return carry + y, ns
+        with jax.named_scope("ssm"):
+            x = apply_norm(lp["ln"], carry, cfg.norm_eps)
+            y, ns = ssm_mod.mamba_apply(lp["mamba"], x, cfg, state=st,
+                                        backend=backend)
+            return carry + y, ns
 
     def group_body(carry, xs):
         hcur = carry
         glp, gst, la, lb, sid, kv = xs
         hcur, gns = jax.lax.scan(mamba_body, hcur, (glp, gst))
         sp = jax.tree.map(lambda x: x[sid], p["shared"])
-        cat = jnp.concatenate([hcur, jnp.broadcast_to(e0, hcur.shape)], -1)
-        w = sp["in_proj"]["w"].astype(cat.dtype) + (
-            la.astype(cat.dtype) @ lb.astype(cat.dtype))
-        xin = cat @ w
+        with jax.named_scope("attn"):       # the shared block's input
+            cat = jnp.concatenate([hcur, jnp.broadcast_to(e0, hcur.shape)],
+                                  -1)
+            w = sp["in_proj"]["w"].astype(cat.dtype) + (
+                la.astype(cat.dtype) @ lb.astype(cat.dtype))
+            xin = cat @ w
         y, nkv, _ = block_apply(sp["block"], xin, cfg, positions=positions,
                                 cache=kv, backend=backend, causal=True)
         return hcur + (y - xin), (gns, nkv)   # residual on the block's delta
@@ -297,10 +307,11 @@ def hybrid_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
     if remat and caches is None:
         group_body = jax.checkpoint(group_body, prevent_cse=False)
 
-    h, (gns, nkv) = jax.lax.scan(
-        group_body, h,
-        (gp, gc if gc is not None else None, p["lora_a"], p["lora_b"],
-         shared_ids, kvc))
+    with jax.named_scope("stack"):
+        h, (gns, nkv) = jax.lax.scan(
+            group_body, h,
+            (gp, gc if gc is not None else None, p["lora_a"], p["lora_b"],
+             shared_ids, kvc))
     new_caches = None
     if caches is not None:
         new_caches = {
@@ -313,7 +324,8 @@ def hybrid_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
         body = mamba_body
         if remat and caches is None:
             body = jax.checkpoint(mamba_body, prevent_cse=False)
-        h, tns = jax.lax.scan(body, h, (p["tail"], tc))
+        with jax.named_scope("stack"):
+            h, tns = jax.lax.scan(body, h, (p["tail"], tc))
         if caches is not None:
             new_caches["tail"] = tns
     return h, new_caches, jnp.float32(0)

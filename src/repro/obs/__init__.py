@@ -12,6 +12,9 @@ The runtime's ledgers answer "how much"; this package answers "when",
 * :mod:`repro.obs.metrics` — counters / gauges / histograms with exact
   percentiles; instrumented in ``PIMRuntime``, ``PIMCluster``,
   ``DecodeOffload`` and the serve loop (TTFT/TPOT).
+* :mod:`repro.obs.spans` — ``span``: host spans with args in the JAX
+  profiler's own trace, on the device's clock (the serve loop's
+  ``server.*`` phases).
 
 ``python -m repro.obs <file>`` summarizes a ``.trace`` file, a Chrome
 trace JSON, or a dumped :class:`ProfileReport`.  See
@@ -31,6 +34,7 @@ from repro.obs.profile import (
     export_chrome_trace,
     profile_report,
 )
+from repro.obs.spans import span
 
 __all__ = [
     "Counter",
@@ -45,4 +49,5 @@ __all__ = [
     "critical_path",
     "export_chrome_trace",
     "profile_report",
+    "span",
 ]

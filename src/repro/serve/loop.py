@@ -65,6 +65,7 @@ from repro.configs.base import ArchConfig
 from repro.core.isa import PIM_FREQ_HZ
 from repro.models import model as lm
 from repro.obs.metrics import Histogram
+from repro.obs.spans import span
 from repro.runtime.cluster import HostLinkLedger
 from repro.serve.offload import DecodeOffload
 from repro.serve.traffic import (SLO, HostCostModel, SimClock, Trace,
@@ -147,12 +148,16 @@ class Server:
                 as_plan(faults).serve_faults,
                 key=lambda f: (f.at_iter, f.slot))
 
-        self._decode = jax.jit(
-            lambda p, t, ps, c: lm.decode_step(p, t, ps, c, cfg),
-            donate_argnums=(3,))
-        self._prefill_one = jax.jit(
-            lambda p, toks: lm.prefill(p, {"tokens": toks}, cfg,
-                                       cache_len=cache_len))
+        # named functions, so that the device trace shows the programs as
+        # jit_server_decode(<hash>) and jit_server_prefill(<hash>)
+        def server_decode(p, t, ps, c):
+            return lm.decode_step(p, t, ps, c, cfg)
+
+        def server_prefill(p, toks):
+            return lm.prefill(p, {"tokens": toks}, cfg, cache_len=cache_len)
+
+        self._decode = jax.jit(server_decode, donate_argnums=(3,))
+        self._prefill_one = jax.jit(server_prefill)
 
     def _check_prompt(self, req: Request) -> None:
         """A prompt must leave at least one cache position for decode —
@@ -248,39 +253,54 @@ class Server:
                 if idx is None:
                     return           # everything queued is backing off
                 req = self.queue.pop(idx)
-                self._check_prompt(req)
-                req.admitted_at = self.clock.now
-                if self.metrics is not None:
-                    self.metrics.histogram(
-                        "serve.queue_delay_s", unit="s",
-                        help="queue wait (submit -> prefill start)"
-                    ).record(req.admitted_at - req.submitted_at)
-                logits, fresh = self._prefill_one(
-                    self.params, jnp.asarray(req.prompt[None, :]))
-                # splice slot i's cache from the single-seq prefill cache
-                self.caches = jax.tree.map(
-                    lambda full, one, _i=i: _splice(full, one, _i, self.cfg),
-                    self.caches, fresh)
-                tok = int(jnp.argmax(logits[0]))
-                req.out_tokens.append(tok)
-                # the prefill's argmax IS the request's first token:
-                # TTFT closes here, before any decode step runs.  The
-                # virtual clock charges the host-prefill roofline (a
-                # WallClock ignores the advance and reads real time)
-                self.clock.advance(self.cost.prefill_s(len(req.prompt)))
-                req.first_token_at = self.clock.now
-                if self.metrics is not None:
-                    self.metrics.histogram(
-                        "serve.ttft_s", unit="s",
-                        help="time to first token (submit -> prefill "
-                             "argmax)").record(
-                        req.first_token_at - req.submitted_at)
-                self.active[i] = req
-                self.pos[i] = len(req.prompt)
-                # host prefill produced the prompt's KV: ship it onto
-                # the sidecar's PIM pages once, decode grows it in place
-                if self._kv is not None:
-                    self.pim_offload.kv_prefill(req.uid, len(req.prompt))
+                with span("server.admit", uid=req.uid,
+                          prompt_len=len(req.prompt)):
+                    self._admit_one(i, req)
+
+    def _admit_one(self, i: int, req: Request):
+        """Prefill ``req`` and splice its cache into slot ``i``."""
+        self._check_prompt(req)
+        req.admitted_at = self.clock.now
+        if self.metrics is not None:
+            m = self.metrics
+            m.histogram(
+                "serve.queue_delay_s", unit="s",
+                help="queue wait (submit -> prefill start)"
+            ).record(req.admitted_at - req.submitted_at)
+            m.counter("serve.admissions", unit="requests",
+                      help="requests prefilled into a slot").inc()
+            m.counter("serve.prefill_tokens", unit="tokens",
+                      help="prompt tokens prefilled").inc(len(req.prompt))
+        with span("server.prefill"):
+            logits, fresh = self._prefill_one(
+                self.params, jnp.asarray(req.prompt[None, :]))
+        # splice slot i's cache from the single-seq prefill cache
+        with span("server.splice"):
+            self.caches = jax.tree.map(
+                lambda full, one: _splice(full, one, i, self.cfg),
+                self.caches, fresh)
+        # the host waits here for the prefill and the splice
+        with span("server.first_token"):
+            tok = int(jnp.argmax(logits[0]))
+        req.out_tokens.append(tok)
+        # the prefill's argmax IS the request's first token:
+        # TTFT closes here, before any decode step runs.  The
+        # virtual clock charges the host-prefill roofline (a
+        # WallClock ignores the advance and reads real time)
+        self.clock.advance(self.cost.prefill_s(len(req.prompt)))
+        req.first_token_at = self.clock.now
+        if self.metrics is not None:
+            self.metrics.histogram(
+                "serve.ttft_s", unit="s",
+                help="time to first token (submit -> prefill "
+                     "argmax)").record(
+                req.first_token_at - req.submitted_at)
+        self.active[i] = req
+        self.pos[i] = len(req.prompt)
+        # host prefill produced the prompt's KV: ship it onto
+        # the sidecar's PIM pages once, decode grows it in place
+        if self._kv is not None:
+            self.pim_offload.kv_prefill(req.uid, len(req.prompt))
 
     def _retire(self, i: int):
         req = self.active[i]
@@ -304,9 +324,26 @@ class Server:
                     (req.finished_at - req.first_token_at)
                     / (len(req.out_tokens) - 1))
 
+    def _kv_positions(self, live: List[int]) -> Tuple[int, int]:
+        """Cache positions of one decode step: (live, scanned).  Live is
+        what the ``live`` slots have written, ``pos + 1`` each; scanned is
+        what the decode program attends over, every position of every
+        slot's cache (``attention_apply`` decodes with ``kv_valid=None``).
+        An SSM has no positions to attend over."""
+        if self.cfg.family == "ssm":
+            return 0, 0
+        per_slot = min(self.cache_len, self.cfg.sliding_window) \
+            if self.cfg.sliding_window else self.cache_len
+        kv_live = sum(min(int(self.pos[i]) + 1, per_slot) for i in live)
+        return kv_live, self.slots * per_slot
+
     def step(self):
         """One serving iteration: fire serve faults, admit, batched
         decode, retire; count the iteration against the step deadline."""
+        with span("server.step", iter=self._iter + 1):
+            return self._step()
+
+    def _step(self):
         track_wall = self.metrics is not None \
             or self.step_deadline_s is not None
         t0 = time.time() if track_wall else 0.0
@@ -322,9 +359,22 @@ class Server:
         toks = np.zeros((self.slots, 1), np.int32)
         for i in live:
             toks[i, 0] = self.active[i].out_tokens[-1]
-        logits, self.caches = self._decode(
-            self.params, jnp.asarray(toks),
-            jnp.asarray(self.pos), self.caches)
+        kv_live, kv_scanned = self._kv_positions(live)
+        with span("server.decode", live=len(live), kv_live=kv_live,
+                  kv_scanned=kv_scanned):
+            logits, self.caches = self._decode(
+                self.params, jnp.asarray(toks),
+                jnp.asarray(self.pos), self.caches)
+        if self.metrics is not None:
+            m = self.metrics
+            m.counter("serve.decode_steps", unit="steps",
+                      help="batched decode steps").inc()
+            m.counter("serve.kv_live_positions", unit="positions",
+                      help="cache positions live slots had written, "
+                           "summed over decode steps").inc(kv_live)
+            m.counter("serve.kv_scanned_positions", unit="positions",
+                      help="cache positions decode attended over, "
+                           "summed over decode steps").inc(kv_scanned)
         rec = None
         if self.pim_offload is not None:
             rec = self.pim_offload.step(
@@ -334,7 +384,9 @@ class Server:
         # makespan when a sidecar ran it, else the host decode roofline
         self.clock.advance(rec.pim_s if rec is not None
                            else self.cost.decode_step_s(len(live)))
-        nxt = np.asarray(jnp.argmax(logits, -1))
+        # the host waits here for the decode
+        with span("server.sample"):
+            nxt = np.asarray(jnp.argmax(logits, -1))
         for i in live:
             req = self.active[i]
             req.out_tokens.append(int(nxt[i]))
@@ -357,10 +409,6 @@ class Server:
                 self.metrics.histogram(
                     "serve.step_s", unit="s",
                     help="serving-iteration wall time").record(wall)
-                self.metrics.gauge(
-                    "serve.live_slots", unit="slots",
-                    help="slots decoding in the last iteration").set(
-                    len(live))
         return True
 
     def run_until_drained(self, max_iters: int = 10_000,

@@ -3,8 +3,10 @@
 Memory-linear by construction: training/prefill attention is a chunked
 online-softmax scan over KV blocks (the pure-jnp twin of the Pallas flash
 kernel — same math, lowered by XLA for the dry-run), so 32k prefill never
-materializes a T x T score matrix.  Decode uses the same routine with Tq=1
-against the cache.
+materializes a T x T score matrix.  Decode (one new token per row) writes
+that token into the layer-stacked cache in place and attends over the
+layer's slice as it is stored (``decode_attention``): no chunk-major or
+float32 copy of the cache.
 
 Sharding posture (single/multi-pod mesh): q heads shard on 'model'; KV
 tensors shard on heads when divisible, else on head_dim (partial scores are
@@ -35,19 +37,16 @@ NEG = -1e30
 
 
 def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                      chunk: int = 1024, q_chunk: int = 512, q_offset=0,
-                      kv_positions: Optional[jnp.ndarray] = None,
-                      kv_valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                      chunk: int = 1024, q_chunk: int = 512,
+                      q_offset=0) -> jnp.ndarray:
     """q (B,Tq,H,D), k/v (B,Tk,Hkv,Dv?) -> (B,Tq,H,Dv).
 
     Memory-linear in BOTH directions: an outer scan over q blocks wraps the
     inner online-softmax scan over KV blocks, so the largest live score
     tensor is (B, q_chunk, H, chunk).
 
-    ``q_offset``: absolute position of q[0] (scalar or (B,)).
-    ``kv_positions``: absolute positions of cache slots (B,Tk) for rolling
-    caches; defaults to 0..Tk-1.  ``kv_valid``: scalar/(B,) count of valid
-    cache slots (defaults to all).
+    ``q_offset``: absolute position of q[0] (scalar or (B,)); k/v sit at
+    positions 0..Tk-1.
     """
     b, tq, h, d = q.shape
     if tq > q_chunk:
@@ -62,8 +61,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
             qi, off = inp
             out = chunked_attention(
                 qi, k, v, causal=causal, window=window, chunk=chunk,
-                q_chunk=q_chunk, q_offset=off, kv_positions=kv_positions,
-                kv_valid=kv_valid)
+                q_chunk=q_chunk, q_offset=off)
             return None, out
 
         _, outs = jax.lax.scan(
@@ -81,16 +79,9 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        if kv_positions is not None:
-            kv_positions = jnp.pad(kv_positions, ((0, 0), (0, pad)),
-                                   constant_values=2 ** 30)
     nb = (tk + pad) // chunk
-    if kv_positions is None:
-        kv_positions = jnp.broadcast_to(jnp.arange(tk + pad)[None], (b, tk + pad))
-    if kv_valid is None:
-        kv_valid = jnp.full((b,), tk, jnp.int32)
-    else:
-        kv_valid = jnp.broadcast_to(jnp.asarray(kv_valid, jnp.int32), (b,))
+    kv_positions = jnp.broadcast_to(jnp.arange(tk + pad)[None], (b, tk + pad))
+    kv_valid = jnp.full((b,), tk, jnp.int32)
     qpos = (jnp.broadcast_to(jnp.asarray(q_offset), (b,))[:, None]
             + jnp.arange(tq)[None, :])                       # (B, Tq)
 
@@ -108,7 +99,6 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
         slot = slot0 + jnp.arange(kb.shape[1])
         ok = slot[None, :, None] < kv_valid[:, None, None]   # (B,chunk,1)
         mask = jnp.transpose(ok, (0, 2, 1))[:, :, None, None, :]
-        mask = mask & (kpos >= 0)          # -1 marks unwritten cache slots
         if causal:
             mask = mask & (kpos <= qp)
         if window > 0:
@@ -134,6 +124,37 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
          pc.transpose(1, 0, 2), jnp.arange(nb) * chunk))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.reshape(b, tq, h, dv).astype(q.dtype)
+
+
+def decode_attention(q, k, v, *, q_pos, kv_pos, window: int = 0):
+    """One new token per row against a whole cache, read as it is stored.
+
+    q (B,H,D), k (B,S,Hkv,D), v (B,S,Hkv,Dv) -> (B,H,Dv) float32.  ``q`` and
+    ``k`` may also be tuples of parts split along D (MLA's latent and rope
+    halves): their scores add up, so no concatenated copy of the cache is
+    made.  ``q_pos`` (B,) is each row's position, ``kv_pos`` (B,S) each
+    cache slot's (-1 where unwritten).  The cache dtype goes into both dots
+    unconverted; scores, softmax and accumulation are float32.
+    """
+    qs, ks = (q, k) if isinstance(q, tuple) else ((q,), (k,))
+    b, h = qs[0].shape[:2]
+    hkv = v.shape[2]
+    scale = sum(x.shape[-1] for x in qs) ** -0.5
+    s = sum(jnp.einsum("bhgd,bkhd->bhgk",
+                       qi.reshape(b, hkv, h // hkv, -1).astype(jnp.float32),
+                       ki, preferred_element_type=jnp.float32)
+            for qi, ki in zip(qs, ks)) * scale             # (B,Hkv,g,S)
+    kpos = kv_pos[:, None, None, :]
+    qp = q_pos[:, None, None, None]
+    mask = (kpos >= 0) & (kpos <= qp)      # -1 marks unwritten cache slots
+    if window > 0:
+        mask = mask & (kpos > qp - window)
+    s = jnp.where(mask, s, NEG)
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    out = jnp.einsum("bhgk,bkhd->bhgd", p, v,
+                     preferred_element_type=jnp.float32)
+    out = out / jnp.maximum(p.sum(-1), 1e-30)[..., None]
+    return out.reshape(b, h, -1)
 
 
 # ---------------------------------------------------------------------------
@@ -174,10 +195,12 @@ def make_cache(cfg: ArchConfig, batch: int, length: int, dtype,
 
 
 def attention_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
-                    backend: Backend = XLA, causal=True,
+                    layer=None, backend: Backend = XLA, causal=True,
                     chunk: int = 1024) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """x (B,T,d).  Training/prefill: cache is None or gets filled.
-    Decode: T==1, cache is read+updated (rolling for SWA)."""
+    Decode: T==1, ``cache`` is the layer-stacked cache (L,B,S,...) and
+    ``layer`` this layer's index in it; the new entries are written in
+    place (rolling for SWA) and the layer's slice is read."""
     b, t, d = x.shape
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     q = dense(p["wq"], x, backend).reshape(b, t, h, hd)
@@ -219,39 +242,41 @@ def attention_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
                                 window=cfg.sliding_window, chunk=chunk,
                                 q_offset=positions[:, 0])
     else:
-        # decode: write the new kv into its slot, attend over the cache
+        # decode: write the new kv at [layer, row, slot] of the stacked
+        # cache, attend over the layer's slice
         from repro.sharding.context import current_mesh
         mesh = current_mesh()
         msize = mesh.shape.get("model", 1) if mesh else 1
         heads_shardable = hkv % max(msize, 1) == 0
-        clen = cache["k"].shape[1]
+        clen = cache["k"].shape[2]
         pos = positions[:, 0] if positions.ndim > 1 else positions  # (B,)
         slot = (pos % clen) if cfg.sliding_window else pos
         bi = jnp.arange(b)
         with jax.named_scope("kv_write"):
-            newk = cache["k"].at[bi, slot].set(
-                k[:, 0].astype(cache["k"].dtype))
-            newv = cache["v"].at[bi, slot].set(
-                v[:, 0].astype(cache["v"].dtype))
-            npos = cache["pos"].at[bi, slot].set(pos.astype(jnp.int32))
-        new_cache = {"k": newk, "v": newv, "pos": npos}
-        kv_valid = jnp.minimum(pos + 1, clen)
+            new_cache = {
+                "k": cache["k"].at[layer, bi, slot].set(
+                    k[:, 0].astype(cache["k"].dtype)),
+                "v": cache["v"].at[layer, bi, slot].set(
+                    v[:, 0].astype(cache["v"].dtype)),
+                "pos": cache["pos"].at[layer, bi, slot].set(
+                    pos.astype(jnp.int32)),
+            }
+        kk, vv = new_cache["k"][layer], new_cache["v"][layer]
         if heads_shardable:
-            kk = constrain(newk, "batch", None, "model", None)
-            vv = constrain(newv, "batch", None, "model", None)
+            kk = constrain(kk, "batch", None, "model", None)
+            vv = constrain(vv, "batch", None, "model", None)
         else:
             # KV heads don't divide the model axis: shard head_dim on both
             # q and kv so the score contraction is over the sharded dim —
-            # a small all-reduce of (B,H,Tk) partials instead of per-chunk
-            # cache all-gathers
+            # a small all-reduce of (B,H,Tk) partials instead of cache
+            # all-gathers
             q = constrain(q, "batch", None, None, "model")
-            kk = constrain(newk, "batch", None, None, "model")
-            vv = constrain(newv, "batch", None, None, "model")
-        out = chunked_attention(
-            q, kk, vv,
-            causal=True, window=cfg.sliding_window, chunk=chunk,
-            q_offset=pos, kv_positions=npos,
-            kv_valid=None if not cfg.sliding_window else kv_valid)
+            kk = constrain(kk, "batch", None, None, "model")
+            vv = constrain(vv, "batch", None, None, "model")
+        out = decode_attention(q[:, 0], kk, vv, q_pos=pos,
+                               kv_pos=new_cache["pos"][layer],
+                               window=cfg.sliding_window)
+        out = out[:, None].astype(q.dtype)
     out = constrain(out, "batch", None, "model", None)
     y = dense(p["wo"], out.reshape(b, t, h * hd), backend)
     return out_constrain(y, cfg.policy), new_cache
@@ -292,8 +317,10 @@ def mla_make_cache(cfg: ArchConfig, batch: int, length: int, dtype,
             "kr": jnp.zeros(shape_r, dtype)}
 
 
-def mla_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
+def mla_apply(p, x, cfg: ArchConfig, *, positions, cache=None, layer=None,
               backend: Backend = XLA, chunk: int = 1024):
+    """Like ``attention_apply``: decode (T==1) writes into the layer-stacked
+    latent cache at ``layer`` and reads the layer's slice."""
     m = cfg.mla
     b, t, d = x.shape
     h = cfg.n_heads
@@ -308,19 +335,34 @@ def mla_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
     kr = rope(dense(p["wkr"], x, backend)[:, :, None, :], positions,
               cfg.rope_theta)[:, :, 0]                        # shared head
 
+    # absorbed form: fold W_uk into q, attend directly against the latent —
+    # the compressed cache is both k and v (reduction-free: no per-head KV
+    # expansion is ever materialized for decode)
+    wuk = p["wuk"]["w"].astype(q.dtype).reshape(m.kv_lora_rank, h, nd)
+    q_lat = jnp.einsum("bthn,rhn->bthr", qn, wuk)             # (B,T,H,r)
+    scale_fix = ((nd + rd) ** -0.5) / ((m.kv_lora_rank + rd) ** -0.5)
+
     new_cache = None
     if cache is not None and t == 1:
         pos = positions[:, 0] if positions.ndim > 1 else positions
         bi = jnp.arange(b)
         with jax.named_scope("kv_write"):
-            ckv_c = cache["ckv"].at[bi, pos].set(
-                ckv[:, 0].astype(cache["ckv"].dtype))
-            kr_c = cache["kr"].at[bi, pos].set(
-                kr[:, 0].astype(cache["kr"].dtype))
-        new_cache = {"ckv": ckv_c, "kr": kr_c}
-        ckv_all, kr_all = ckv_c, kr_c
+            new_cache = {
+                "ckv": cache["ckv"].at[layer, bi, pos].set(
+                    ckv[:, 0].astype(cache["ckv"].dtype)),
+                "kr": cache["kr"].at[layer, bi, pos].set(
+                    kr[:, 0].astype(cache["kr"].dtype)),
+            }
+        ckv_l = constrain(new_cache["ckv"][layer], "batch", None, None)
+        kr_l = constrain(new_cache["kr"][layer], "batch", None, None)
+        qs = tuple(constrain(qi[:, 0] * scale_fix, "batch", "model", None)
+                   for qi in (q_lat, qr))
+        slots = jnp.arange(ckv_l.shape[1])
+        out = decode_attention(
+            qs, (ckv_l[:, :, None], kr_l[:, :, None]), ckv_l[:, :, None],
+            q_pos=pos, kv_pos=jnp.broadcast_to(slots, (b, slots.size)))
+        out = out[:, None].astype(q.dtype)                    # (B,1,H,r)
     else:
-        ckv_all, kr_all = ckv, kr
         if cache is not None:  # prefill fills the cache
             with jax.named_scope("kv_write"):
                 new_cache = {
@@ -330,25 +372,18 @@ def mla_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
                     "kr": jax.lax.dynamic_update_slice(
                         cache["kr"], kr.astype(cache["kr"].dtype), (0, 0, 0)),
                 }
-
-    # absorbed form: fold W_uk into q, attend directly against the latent —
-    # the compressed cache is both k and v (reduction-free: no per-head KV
-    # expansion is ever materialized for decode)
-    wuk = p["wuk"]["w"].astype(q.dtype).reshape(m.kv_lora_rank, h, nd)
-    q_lat = jnp.einsum("bthn,rhn->bthr", qn, wuk)             # (B,T,H,r)
-    qq = jnp.concatenate([q_lat, qr], -1)                     # (B,T,H,r+rd)
-    qq = constrain(qq, "batch", None, "model", None)
-    kk = jnp.concatenate([ckv_all, kr_all], -1)[:, :, None, :]  # (B,Tk,1,r+rd)
-    # gather the latent KV across the seq dim ONCE per layer (with SP the
-    # inputs arrive seq-sharded; without this, every KV-chunk slice in the
-    # attention scan triggers its own gather)
-    kk = constrain(kk, "batch", None, None, None)
-    ckv_all = constrain(ckv_all, "batch", None, None)
-    scale_fix = ((nd + rd) ** -0.5) / ((m.kv_lora_rank + rd) ** -0.5)
-    out = chunked_attention(
-        qq * scale_fix, kk, ckv_all[:, :, None, :], causal=True, chunk=chunk,
-        q_offset=(positions[:, 0] if positions.ndim > 1 else positions),
-        kv_valid=None)                                        # (B,T,H,r)
+        qq = jnp.concatenate([q_lat, qr], -1)                 # (B,T,H,r+rd)
+        qq = constrain(qq, "batch", None, "model", None)
+        kk = jnp.concatenate([ckv, kr], -1)[:, :, None, :]
+        # gather the latent KV across the seq dim ONCE per layer (with SP
+        # the inputs arrive seq-sharded; without this, every KV-chunk slice
+        # in the attention scan triggers its own gather)
+        kk = constrain(kk, "batch", None, None, None)
+        ckv = constrain(ckv, "batch", None, None)
+        out = chunked_attention(                              # (B,T,H,r)
+            qq * scale_fix, kk, ckv[:, :, None, :], causal=True,
+            chunk=chunk,
+            q_offset=(positions[:, 0] if positions.ndim > 1 else positions))
     wuv = p["wuv"]["w"].astype(q.dtype).reshape(m.kv_lora_rank, h, vd)
     out = jnp.einsum("bthr,rhv->bthv", out, wuv)
     y = dense(p["wo"], out.reshape(b, t, h * vd), backend)

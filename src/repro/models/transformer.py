@@ -1,8 +1,9 @@
 """Blocks and stacks: decoder/encoder transformer, MoE, SSM, Zamba2 hybrid.
 
 All stacks scan over layer-stacked parameters (compact HLO at 61-80 layers)
-with optional per-layer remat.  Decode caches are layer-stacked pytrees
-threaded through the same scans.
+with optional per-layer remat.  Caches are layer-stacked pytrees: prefill
+threads them through the scans' xs/ys; decode carries the attention caches
+and updates them in place (``_scan_in_place``).
 """
 from __future__ import annotations
 
@@ -66,7 +67,7 @@ def block_init(key, cfg: ArchConfig, dtype, use_moe: bool):
     return p
 
 
-def block_apply(p, h, cfg: ArchConfig, *, positions, cache=None,
+def block_apply(p, h, cfg: ArchConfig, *, positions, cache=None, layer=None,
                 backend: Backend = XLA, causal=True):
     # named scopes: each operation's op_name says which half of the block
     # it belongs to, in the compiled program and in a profiler trace
@@ -75,11 +76,12 @@ def block_apply(p, h, cfg: ArchConfig, *, positions, cache=None,
         if cfg.mla is not None:
             a, new_cache = attn_mod.mla_apply(p["attn"], x, cfg,
                                               positions=positions,
-                                              cache=cache, backend=backend)
+                                              cache=cache, layer=layer,
+                                              backend=backend)
         else:
             a, new_cache = attn_mod.attention_apply(
                 p["attn"], x, cfg, positions=positions, cache=cache,
-                backend=backend, causal=causal)
+                layer=layer, backend=backend, causal=causal)
         a = jax.ad_checkpoint.checkpoint_name(a, "blk_out")
         h = h + a
     with jax.named_scope("moe" if "moe" in p else "mlp"):
@@ -103,10 +105,33 @@ def block_apply(p, h, cfg: ArchConfig, *, positions, cache=None,
 # ---------------------------------------------------------------------------
 
 
+def _scan_in_place(fn, h, xs, caches):
+    """Decode scan over stacked layers: ``fn(h, caches, x, l) -> (h, caches,
+    y)`` updates the layer-stacked ``caches`` at layer ``l`` and they ride
+    in the carry, so the loop aliases the (donated) buffers end to end.
+    Threading them through xs/ys instead copies each layer's cache out and
+    back and the whole stack once more."""
+    n = jax.tree_util.tree_leaves(xs)[0].shape[0]
+
+    def body(carry, inp):
+        x, l = inp
+        hh, c, y = fn(*carry, x, l)
+        return (hh, c), y
+
+    (h, caches), ys = jax.lax.scan(body, (h, caches), (xs, jnp.arange(n)))
+    return h, caches, ys
+
+
 def _scan_blocks(params_stack, h, cfg, *, positions, caches, backend, causal,
                  remat: bool):
     fn = functools.partial(block_apply, cfg=cfg, positions=positions,
                            backend=backend, causal=causal)
+    if caches is not None and h.shape[1] == 1:
+        with jax.named_scope("stack"):
+            h, new_caches, auxs = _scan_in_place(
+                lambda hh, c, p, l: fn(p, hh, cache=c, layer=l),
+                h, params_stack, caches)
+        return h, new_caches, jnp.sum(auxs)
 
     def body(carry, xs):
         p, c = xs
@@ -289,9 +314,8 @@ def hybrid_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
                                         backend=backend)
             return carry + y, ns
 
-    def group_body(carry, xs):
-        hcur = carry
-        glp, gst, la, lb, sid, kv = xs
+    def group(hcur, kv, xs, layer):
+        glp, gst, la, lb, sid = xs
         hcur, gns = jax.lax.scan(mamba_body, hcur, (glp, gst))
         sp = jax.tree.map(lambda x: x[sid], p["shared"])
         with jax.named_scope("attn"):       # the shared block's input
@@ -301,17 +325,23 @@ def hybrid_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
                 la.astype(cat.dtype) @ lb.astype(cat.dtype))
             xin = cat @ w
         y, nkv, _ = block_apply(sp["block"], xin, cfg, positions=positions,
-                                cache=kv, backend=backend, causal=True)
-        return hcur + (y - xin), (gns, nkv)   # residual on the block's delta
+                                cache=kv, layer=layer, backend=backend,
+                                causal=True)
+        return hcur + (y - xin), nkv, gns   # residual on the block's delta
+
+    def group_body(carry, xs_kv):
+        hcur, nkv, gns = group(carry, xs_kv[1], xs_kv[0], None)
+        return hcur, (gns, nkv)
 
     if remat and caches is None:
         group_body = jax.checkpoint(group_body, prevent_cse=False)
 
+    xs = (gp, gc, p["lora_a"], p["lora_b"], shared_ids)
     with jax.named_scope("stack"):
-        h, (gns, nkv) = jax.lax.scan(
-            group_body, h,
-            (gp, gc if gc is not None else None, p["lora_a"], p["lora_b"],
-             shared_ids, kvc))
+        if caches is not None and h.shape[1] == 1:
+            h, nkv, gns = _scan_in_place(group, h, xs, kvc)
+        else:
+            h, (gns, nkv) = jax.lax.scan(group_body, h, (xs, kvc))
     new_caches = None
     if caches is not None:
         new_caches = {

@@ -328,7 +328,8 @@ class Server:
         """Cache positions of one decode step: (live, scanned).  Live is
         what the ``live`` slots have written, ``pos + 1`` each; scanned is
         what the decode program attends over, every position of every
-        slot's cache (``attention_apply`` decodes with ``kv_valid=None``).
+        slot's cache (``attention.decode_attention`` masks dead slots, it
+        does not skip them).
         An SSM has no positions to attend over."""
         if self.cfg.family == "ssm":
             return 0, 0

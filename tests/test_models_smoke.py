@@ -109,6 +109,44 @@ def test_decode_matches_full_forward(name):
         atol=2e-2, rtol=2e-2)
 
 
+@pytest.mark.parametrize("name", ["qwen3-1.7b", "mixtral-8x22b",
+                                  "deepseek-v3-671b"])
+def test_ragged_slots_decode_matches_full_forward(name):
+    """Rows at different positions in one decode batch, as ``Server`` runs
+    them: each row is prefilled alone, spliced into its slot of the batch
+    cache, then decoded together with the others.  Row 1 runs past the
+    sliding window's wrap (mixtral: window 16).  Each row's last logits
+    must match its own full forward — a write at the wrong [layer, row,
+    slot] of the stacked cache shows here, not with equal positions."""
+    import dataclasses
+    from repro.serve.loop import _splice
+    cfg = get(name).reduced()
+    if cfg.moe:
+        cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
+    params = lm.init(cfg, jax.random.PRNGKey(4))
+    prompt_lens, steps, cache_len = (1, 12, 6), 12, 24
+    tokens = jnp.asarray(RNG.integers(0, cfg.vocab_size,
+                                      (len(prompt_lens), cache_len)),
+                         jnp.int32)
+    caches = lm.make_caches(cfg, len(prompt_lens), cache_len)
+    for i, n in enumerate(prompt_lens):
+        _, one = lm.prefill(params, {"tokens": tokens[i:i + 1, :n]}, cfg,
+                            cache_len=cache_len)
+        caches = jax.tree.map(lambda full, o: _splice(full, o, i, cfg),
+                              caches, one)
+    step = jax.jit(lambda p, tk, ps, c: lm.decode_step(p, tk, ps, c, cfg))
+    pos = np.asarray(prompt_lens, np.int32)
+    for _ in range(steps):
+        logits, caches = step(params, tokens[np.arange(len(pos)), pos][:, None],
+                              jnp.asarray(pos), caches)
+        pos = pos + 1
+    for i, n in enumerate(pos):
+        full, _ = lm.prefill(params, {"tokens": tokens[i:i + 1, :n]}, cfg,
+                             cache_len=int(n))
+        np.testing.assert_allclose(np.asarray(logits[i]),
+                                   np.asarray(full[0]), atol=2e-2, rtol=2e-2)
+
+
 def test_moe_capacity_drops():
     """With a tiny capacity factor, some tokens are dropped (output becomes
     the shared/residual path only) — outputs change but stay finite."""

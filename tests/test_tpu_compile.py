@@ -12,6 +12,8 @@ one process may load the TPU library, and every pytest worker imports
 this file.
 """
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,3 +94,83 @@ def test_ssd_scan_compiles_for_v5e(one_chip, t):
                         ((bh, t), F32), ((bh, t, n), BF16),
                         ((bh, t, n), BF16))
     assert "tpu_custom_call" in hlo
+
+
+# --- the full-width serving decode step: its KV cache stays in place -------
+
+_HLO_COMP = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(([^)]*)\)")
+
+
+def _materialized_ops(hlo: str):
+    """(name, dims, op) of each instruction outside fused computations, a
+    fusion named by what its root does (through bitcasts)."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = _HLO_COMP.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), {"ins": {}, "root": None})
+            continue
+        m = _HLO_INSTR.match(line) if cur is not None else None
+        if m:
+            name, dims, op, operands = m.groups()
+            calls = re.search(r"calls=%([\w.-]+)", line)
+            cur["ins"][name] = (dims, op, operands.split(", ")[0].lstrip("%"),
+                                calls.group(1) if calls else None)
+            if line.lstrip().startswith("ROOT"):
+                cur["root"] = name
+    fused = set(re.findall(r" fusion\(.*calls=%([\w.-]+)", hlo))
+    for cname, comp in comps.items():
+        if cname in fused:
+            continue
+        for name, (dims, op, _, calls) in comp["ins"].items():
+            while op == "fusion" and calls in comps:
+                inner = comps[calls]
+                r = inner["root"]
+                while r in inner["ins"] and inner["ins"][r][1] == "bitcast":
+                    r = inner["ins"][r][2]
+                if r not in inner["ins"]:
+                    break
+                op, calls = inner["ins"][r][1], inner["ins"][r][3]
+            yield name, tuple(int(d) for d in dims.split(",") if d), op
+
+
+def test_server_decode_updates_kv_cache_in_place_for_v5e(one_chip):
+    """Full-width qwen3-1.7b decode as ``Server`` jits it (bfloat16 weights,
+    24 slots x 2048, caches donated).  The step writes each layer's new
+    entries into the stacked cache and reads the layer's slice: no
+    temporary as large as one layer's K slice, and no copy, transpose or
+    dynamic-update-slice of the whole cache or of a layer's worth of it."""
+    import dataclasses
+    from repro.configs.base import get
+    from repro.models import model as lm
+    cfg = get("qwen3-1.7b")
+    cfg = cfg.replace(policy=dataclasses.replace(cfg.policy,
+                                                 param_dtype="bfloat16"))
+    slots, cache_len = 24, 2048
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: lm.init(cfg, jax.random.key(0))))
+    caches = on_chip(jax.eval_shape(
+        lambda: lm.make_caches(cfg, slots, cache_len)))
+    toks = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+
+    def server_decode(p, t, ps, c):
+        return lm.decode_step(p, t, ps, c, cfg)
+
+    compiled = jax.jit(server_decode, donate_argnums=(3,)).lower(
+        params, toks, pos, caches).compile()
+    k_layer = caches["dense_stack"]["k"]
+    layer_elems = k_layer.size // k_layer.shape[0]          # 24x2048x8x128
+    layer_bytes = layer_elems * k_layer.dtype.itemsize      # 100663296
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    copies = [(name, dims, op) for name, dims, op in
+              _materialized_ops(compiled.as_text())
+              if op in ("copy", "transpose", "dynamic-update-slice")
+              and math.prod(dims) in (layer_elems, k_layer.size)]
+    assert not copies, copies

@@ -6,6 +6,9 @@ found by the name that ``BENCHMARK.json`` gives it:
 
 * ``configs/<config>.json``: the published configuration, what the run
   changes from the registry and why, the correctness limit;
+* ``families/<family>.py``: one model family, named by a configuration
+  file: its sizes, served leaves, reference, LM head and counts
+  (``family``);
 * ``traffic/<mix>.json``: arrivals, length distributions, prompt grid, slots;
 * ``metrics/<metric>.py``: one reader per per-layer metric.
 
@@ -13,6 +16,7 @@ The yardstick lives here too: traffic generation (``traffic``), the weights
 (``weights``), the operation and byte counts (``counts``) with the table of
 peaks (``peaks.json``), the reduction of profiler traces (``reduce``), the
 float32 reference (``reference``) and the comparison that decides
-``correct`` (``check``).  Nothing here imports the program except
-``harness`` and ``cells``, which drive the system under test.
+``correct`` (``check``); each hands what depends on the model to its
+family.  Nothing here imports the program except ``harness`` and ``cells``,
+which drive the system under test.
 """

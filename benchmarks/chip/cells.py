@@ -1,7 +1,8 @@
 """Find a cell and everything that belongs to it, by the names in
 ``BENCHMARK.json``.
 
-A cell names a configuration (``configs`` entry, its ``file``), a traffic mix
+A cell names a configuration (``configs`` entry, its ``file``, whose
+``family`` is read by ``<paths[0]>/families/<family>.py``), a traffic mix
 (``<paths[0]>/traffic/<mix>.json``) and the chips it needs.  Its metrics are
 the ``end_to_end`` and ``per_layer`` entries that list it under
 ``workloads``, or that have no such list; each per-layer metric is read by
@@ -71,7 +72,7 @@ def resolve(workload: str, root: Path = REPO_ROOT) -> Cell:
     per_layer = [m for m in bench["per_layer"] if _reports(m, workload)]
     return Cell(
         name=workload, chips=w["chips"], config=config, mix=mix,
-        shape=shapes.from_config(config),
+        shape=shapes.from_config(config, chip_dir),
         end_to_end=[m for m in bench["end_to_end"] if _reports(m, workload)],
         per_layer=per_layer,
         readers={m["name"]: _reader(chip_dir / "metrics" / f"{m['name']}.py")

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chip.reference import F32, decoder, mamba2
+from chip import family
 from chip.shapes import Shape
 
 HEAD_CHUNK = 128                # positions per block of LM-head logits
@@ -64,17 +64,13 @@ def pack(reqs: Sequence[Served], length: int):
     return tokens, targets, mask
 
 
-def _hidden_fn(s: Shape):
-    return decoder.hidden if s.family == "decoder" else mamba2.hidden
-
-
 @functools.partial(jax.jit, static_argnames=("s", "control"))
 def _gaps(params, tokens, targets, mask, s: Shape, control: bool):
     with jax.default_matmul_precision("highest"):
-        hid = _hidden_fn(s)
-        h_ref = hid(params, tokens, s)
-        h_ctl = hid(params, tokens, s, quant=True) if control else h_ref
-        table = params["embed"]["table"][:s.vocab].astype(F32)
+        fam = family.of(s)
+        h_ref = fam.hidden(params, tokens, s)
+        h_ctl = fam.hidden(params, tokens, s, quant=True) if control else h_ref
+        table = fam.head(params, s)                        # (V, d) float32
         b, t, d = h_ref.shape
         nc = t // HEAD_CHUNK
 

@@ -1,0 +1,73 @@
+"""Model families: one file each, ``<paths[0]>/families/<family>.py``.
+
+A configuration file names its family; the harness loads that file by path,
+as it loads a metric reader, so a family in a new file needs no edit
+anywhere else.  A family module provides:
+
+* ``Shape``: a frozen dataclass that extends :class:`chip.shapes.Shape`;
+* ``shape(config) -> Shape``: the sizes of a configuration file, refusing
+  what its reference cannot compute;
+* ``program_sizes(shape) -> dict``: the program's ``ArchConfig`` attributes
+  (dotted paths) that must equal these sizes;
+* ``leaves(shape)``: the served parameter leaves, in a fixed order
+  (``chip.weights.Leaf``);
+* ``hidden(params, tokens, shape, quant=False)``: the float32 reference's
+  final normed hidden states (B, T, d);
+* ``head(params, shape)``: the LM-head rows over the real vocabulary,
+  (vocab, d) in float32;
+* the counts ``non_embedding_params``, ``head_params``,
+  ``weight_read_bytes``, ``decode_token_flops(s, ctx)``,
+  ``prefill_flops(s, tokens)`` and ``decode_step_bytes(s, step)``, where
+  ``step`` is the harness's ``chip.layer.Step`` record.
+
+A family may reuse another's pieces by loading its file:
+``family.load(Path(__file__).with_name("decoder.py"))``.
+"""
+from __future__ import annotations
+
+import hashlib
+import importlib.util
+import sys
+from pathlib import Path
+from types import ModuleType
+from typing import Dict
+
+HERE = Path(__file__).resolve().parent      # the benchmark's own directory
+
+_LOADED: Dict[Path, ModuleType] = {}
+
+
+def load(path: Path) -> ModuleType:
+    """The family module at ``path``, loaded once: its ``Shape`` class is
+    the same object every time."""
+    path = Path(path).resolve()
+    mod = _LOADED.get(path)
+    if mod is None:
+        # a name of its own per path; dataclasses look the module up by name
+        tag = hashlib.sha1(str(path).encode()).hexdigest()[:12]
+        name = f"chip_family_{path.stem.replace('-', '_')}_{tag}"
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except Exception:
+            del sys.modules[name]
+            raise
+        _LOADED[path] = mod
+    return mod
+
+
+def find(name: str, chip_dir: Path = HERE) -> ModuleType:
+    """The family ``name`` of the benchmark directory ``chip_dir``."""
+    path = Path(chip_dir) / "families" / f"{name}.py"
+    if not path.is_file():
+        found = sorted(p.stem for p in path.parent.glob("*.py"))
+        raise ValueError(f"no model family {name!r} in {path.parent} "
+                         f"(families found: {found})")
+    return load(path)
+
+
+def of(shape) -> ModuleType:
+    """The family module whose ``Shape`` made ``shape``."""
+    return sys.modules[type(shape).__module__]

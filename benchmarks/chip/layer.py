@@ -100,7 +100,7 @@ def decode_roofline(w: Window) -> Optional[float]:
     idx = _decode_only(w)
     if w.step_busy_s is None or not idx:
         return None
-    need = sum(counts.decode_step_bytes(w.shape, w.steps[i].ctxs) for i in idx)
+    need = sum(counts.decode_step_bytes(w.shape, w.steps[i]) for i in idx)
     busy = sum(w.step_busy_s[i] for i in idx)
     return 100.0 * need / w.peaks["hbm_bytes_per_s"] / busy
 

@@ -1,9 +1,10 @@
 """Whole runs of tiny cells on the CPU, with the look for a chip skipped.
 
-A cell defined from new files only resolves and runs; a sound run is
-correct; a run with the timed path broken underneath is not, once for each
-fault a serving cell can have.  The float8 control reads wider gaps than
-the program.
+A cell defined from new files only, its model family included, resolves and
+runs; a sound run is correct; a run with the timed path broken underneath
+is not, once for each fault a serving cell can have, nor one whose family
+reads the wrong LM head.  The float8 control reads wider gaps than the
+program.
 """
 from __future__ import annotations
 
@@ -13,11 +14,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from chip import cells, harness
+from chip import cells, family, harness, weights
 from chip.tests import tiny
 
 CASES = {"decoder-backlog": (tiny.DECODER, tiny.BACKLOG),
-         "mamba2-burst": (tiny.MAMBA2, tiny.BURST)}
+         "mamba2-burst": (tiny.MAMBA2, tiny.BURST),
+         "untied-backlog": (tiny.UNTIED, tiny.BACKLOG)}
 
 
 @pytest.fixture
@@ -46,6 +48,32 @@ def test_new_files_resolve(tmp_path):
     assert list(cell.readers) == ["admitted_per_step"]
     cfg = cells.program_config(cell)
     assert (cfg.n_layers, cfg.d_model, cfg.ssm.d_state) == (2, 64, 16)
+
+
+def test_new_family_resolves_from_new_files(tmp_path):
+    """A family defined only in files of the checkout: its leaves are the
+    program's parameter layout, the untied head among them."""
+    from repro.models import model as lm
+    root = tiny.write_root(tmp_path, tiny.UNTIED, tiny.BACKLOG)
+    cell = cells.resolve("tiny.cell", root)
+    assert cell.shape.family == "tiny-untied"
+    assert family.of(cell.shape) is family.find("tiny-untied", root / "bench")
+    cfg = cells.program_config(cell)
+    assert cfg.tie_embeddings is False
+    expected = jax.eval_shape(lambda: lm.init(cfg, jax.random.key(0)))
+    got = jax.eval_shape(lambda: weights.make(cell.shape, 3))
+    assert jax.tree.structure(got) == jax.tree.structure(expected)
+    assert [(a.shape, a.dtype) for a in jax.tree.leaves(got)] == \
+        [(b.shape, b.dtype) for b in jax.tree.leaves(expected)]
+    assert got["head"]["w"].shape == (64, 512)
+
+
+def test_unknown_family_names_the_families_found(tmp_path):
+    root = tiny.write_root(tmp_path, dict(tiny.DECODER, family="no-such"),
+                           tiny.BACKLOG)
+    with pytest.raises(ValueError, match=r"no model family 'no-such'.*"
+                       r"\['decoder', 'mamba2', 'tiny-untied'\]"):
+        cells.resolve("tiny.cell", root)
 
 
 def test_benchmark_cells_resolve():
@@ -79,21 +107,38 @@ def test_sound_run_is_correct(tmp_path, on_cpu, case):
     assert r["device"]["count"] == 1
 
 
-def _alter_tokens(monkeypatch):
-    """Every fifth step, the last token of the first live slot is replaced
-    where it is produced."""
+def _alter_finished(monkeypatch, longest: bool):
+    """As a request finishes, the last token it was served is replaced
+    where it is produced: in each request that finishes longer than every
+    request finished before it in the window (``longest``), or in each of
+    the others.  The check always samples the window's longest finished
+    request, the first of its size, and draws the rest of its sample from
+    the others: the first fault reaches the sample on every run, the second
+    only through the drawn part."""
     from repro.serve.loop import Server
-    step, n = Server.step, {"steps": 0}
+    step, seen = Server.step, {}      # requests already looked at, by id
 
     def faulty(self):
         out = step(self)
-        n["steps"] += 1
-        live = [r for r in self.active if r is not None]
-        if live and n["steps"] % 5 == 0:
-            r = live[0]
-            r.out_tokens[-1] = (r.out_tokens[-1] + 1) % self.cfg.vocab_size
+        top = -1
+        for r in self.completed:
+            size = len(r.prompt) + len(r.out_tokens)
+            if id(r) not in seen:
+                seen[id(r)] = r       # held, so that no id is reused
+                if (size > top) == longest:
+                    r.out_tokens[-1] = \
+                        (r.out_tokens[-1] + 1) % self.cfg.vocab_size
+            top = max(top, size)
         return out
     monkeypatch.setattr(Server, "step", faulty)
+
+
+def _alter_tokens(monkeypatch):
+    _alter_finished(monkeypatch, longest=True)
+
+
+def _alter_tokens_beside_longest(monkeypatch):
+    _alter_finished(monkeypatch, longest=False)
 
 
 def _stale_state(monkeypatch):
@@ -119,8 +164,9 @@ def _no_splice(monkeypatch):
     monkeypatch.setattr(loop, "_splice", lambda full, one, slot, cfg: full)
 
 
-FAULTS = {"token_altered": _alter_tokens, "state_unchanged": _stale_state,
-          "splice_skipped": _no_splice}
+FAULTS = {"token_altered": _alter_tokens,
+          "token_altered_beside_longest": _alter_tokens_beside_longest,
+          "state_unchanged": _stale_state, "splice_skipped": _no_splice}
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -129,6 +175,20 @@ def test_broken_timed_path_is_not_correct(tmp_path, on_cpu, monkeypatch,
                                           case, fault):
     FAULTS[fault](monkeypatch)
     r = run_tiny(tmp_path, case)
+    assert r["correct"] is False
+    gap = r["check"]["max_logit_gap"]
+    assert gap["value"] > gap["limit"]
+
+
+def test_untied_head_read_from_the_table_is_not_correct(tmp_path, on_cpu,
+                                                       monkeypatch):
+    """The family's ``head`` returns the embedding table, not the head the
+    program served through."""
+    root = tiny.write_root(tmp_path, tiny.UNTIED, tiny.BACKLOG)
+    fam = family.find("tiny-untied", root / "bench")
+    monkeypatch.setattr(fam, "head", fam.dec.head)
+    r = harness.run("tiny.cell", 2**31 + 5, 1.0, False, time.perf_counter(),
+                    root)
     assert r["correct"] is False
     gap = r["check"]["max_logit_gap"]
     assert gap["value"] > gap["limit"]

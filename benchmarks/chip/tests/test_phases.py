@@ -28,11 +28,10 @@ from pathlib import Path
 
 import pytest
 
-from chip import layer, phases, reduce
+from chip import family, layer, phases, reduce
 from chip.layer import Step
 from chip.phases import ServerTrace, Span
 from chip.reduce import Event, TraceData
-from chip.shapes import Shape
 
 DATA = Path(__file__).parent / "data"
 DEV = "/device:TPU:0"
@@ -207,9 +206,10 @@ def test_program_and_scope_names():
 
 
 def window(t: TraceData) -> layer.Window:
-    s = Shape(family="decoder", layers=2, d_model=64, vocab=500, norm_eps=1e-6,
-              heads=4, kv_heads=2, head_dim=16, d_ff=128, rope_theta=1e6,
-              qk_norm=True)
+    s = family.find("decoder").Shape(
+        family="decoder", layers=2, d_model=64, vocab=500, norm_eps=1e-6,
+        heads=4, kv_heads=2, head_dim=16, d_ff=128, rope_theta=1e6,
+        qk_norm=True)
     r = reduce.reduce(t)
     # one step per step span: the first admits a prompt, the rest decode
     steps = [Step(0, 1, (64,) if i == 0 else (), (30 + i, 20 + i))

@@ -19,10 +19,9 @@ from __future__ import annotations
 
 import pytest
 
-from chip import counts, layer, reduce
+from chip import counts, family, layer, reduce
 from chip.layer import Step
 from chip.reduce import Event, TraceData
-from chip.shapes import Shape
 
 
 def trace() -> TraceData:
@@ -88,9 +87,10 @@ def test_no_device_operations_is_an_error():
 
 
 def window() -> layer.Window:
-    s = Shape(family="decoder", layers=2, d_model=64, vocab=500, norm_eps=1e-6,
-              heads=4, kv_heads=2, head_dim=16, d_ff=128, rope_theta=1e6,
-              qk_norm=True)
+    s = family.find("decoder").Shape(
+        family="decoder", layers=2, d_model=64, vocab=500, norm_eps=1e-6,
+        heads=4, kv_heads=2, head_dim=16, d_ff=128, rope_theta=1e6,
+        qk_norm=True)
     r = reduce.reduce(trace())
     steps = [Step(10, 30, (64,), (65, 20)), Step(40, 60, (), (66, 21)),
              Step(60, 90, (), (67, 22))]
@@ -111,8 +111,8 @@ def test_admission_and_decode_split():
 def test_roofline_and_mfu_from_counts():
     w = window()
     s = w.shape
-    need = counts.decode_step_bytes(s, (66, 21)) + \
-        counts.decode_step_bytes(s, (67, 22))
+    need = counts.decode_step_bytes(s, Step(40, 60, (), (66, 21))) + \
+        counts.decode_step_bytes(s, Step(60, 90, (), (67, 22)))
     assert layer.decode_roofline(w) == pytest.approx(100 * need / 1e6 / 26)
     flops = counts.prefill_flops(s, 64) + sum(
         counts.decode_token_flops(s, c) for c in (65, 20, 66, 21, 67, 22))

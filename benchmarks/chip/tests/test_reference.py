@@ -12,23 +12,23 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chip import check, weights
-from chip.reference import decoder, mamba2
+from chip import check, family, weights
 from chip.shapes import Shape
 
 
 def shape_of(cfg) -> Shape:
     if cfg.family == "ssm":
         s = cfg.ssm
-        return Shape(family="mamba2", layers=cfg.n_layers, d_model=cfg.d_model,
-                     vocab=cfg.vocab_size, norm_eps=cfg.norm_eps,
-                     d_state=s.d_state, d_conv=s.d_conv, expand=s.expand,
-                     ssm_head_dim=s.head_dim, n_groups=s.n_groups)
-    return Shape(family="decoder", layers=cfg.n_layers, d_model=cfg.d_model,
-                 vocab=cfg.vocab_size, norm_eps=cfg.norm_eps,
-                 heads=cfg.n_heads, kv_heads=cfg.n_kv_heads,
-                 head_dim=cfg.head_dim_, d_ff=cfg.d_ff,
-                 rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm)
+        return family.find("mamba2").Shape(
+            family="mamba2", layers=cfg.n_layers, d_model=cfg.d_model,
+            vocab=cfg.vocab_size, norm_eps=cfg.norm_eps, d_state=s.d_state,
+            d_conv=s.d_conv, expand=s.expand, ssm_head_dim=s.head_dim,
+            n_groups=s.n_groups)
+    return family.find("decoder").Shape(
+        family="decoder", layers=cfg.n_layers, d_model=cfg.d_model,
+        vocab=cfg.vocab_size, norm_eps=cfg.norm_eps, heads=cfg.n_heads,
+        kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim_, d_ff=cfg.d_ff,
+        rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm)
 
 
 def serve_logits(cfg, params, prompts, max_new):
@@ -81,12 +81,13 @@ def test_server_matches_reference_logits(arch):
     prompts = [rng.integers(0, shape.vocab, n, dtype=np.int32)
                for n in (7, 12)]
     reqs, rows = serve_logits(cfg, params, prompts, max_new=6)
-    hidden = decoder.hidden if shape.family == "decoder" else mamba2.hidden
-    table = params["embed"]["table"][:shape.vocab]
+    fam = family.of(shape)
+    table = fam.head(params, shape)
     for r in reqs:
         seq = np.concatenate([r.prompt, r.out_tokens])[None]
         with jax.default_matmul_precision("highest"):
-            ref = np.asarray(hidden(params, jnp.asarray(seq), shape) @ table.T)
+            ref = np.asarray(fam.hidden(params, jnp.asarray(seq), shape)
+                             @ table.T)
         p = len(r.prompt)
         got = np.stack(rows[r.uid])[:, :shape.vocab]
         want = ref[0, p - 1:p - 1 + len(got)]

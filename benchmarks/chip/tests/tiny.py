@@ -1,13 +1,18 @@
 """Tiny cells for CPU tests, defined from new files only.
 
 ``write_root`` lays out a checkout of its own under a temporary directory:
-``BENCHMARK.json``, a configuration file, traffic files and metric readers,
-none of which exists in the repository.  The harness resolves them by name.
+``BENCHMARK.json``, a configuration file, traffic files, metric readers and
+a model family (``tiny-untied``), none of which exists in the repository,
+beside a copy of the benchmark's own families.  The harness resolves them
+by name.
 """
 from __future__ import annotations
 
 import json
+import shutil
 from pathlib import Path
+
+from chip import family
 
 DECODER = {
     "name": "tiny-decoder", "source": "test", "family": "decoder",
@@ -38,6 +43,70 @@ MAMBA2 = {
     "check": {"max_logit_gap": 0.08},
 }
 
+UNTIED = dict(
+    DECODER, name="tiny-untied", family="tiny-untied",
+    config=dict(DECODER["config"], tie_word_embeddings=False),
+    program_overrides=dict(DECODER["program_overrides"],
+                           tie_embeddings=False))
+
+# A family of its own file: the decoder family's pieces with an LM head that
+# is a leaf of its own (``head/w``, d x vocab_rows), as the program serves
+# ``tie_embeddings: false``.
+UNTIED_FAMILY = '''"""Decoder with an untied LM head (test family)."""
+from __future__ import annotations
+
+import dataclasses
+from pathlib import Path
+
+from chip import family, weights
+from chip.reference import F32
+
+dec = family.load(Path(__file__).with_name("decoder.py"))
+
+
+@dataclasses.dataclass(frozen=True)
+class Shape(dec.Shape):
+    pass
+
+
+def shape(config):
+    if config["config"].get("tie_word_embeddings", True):
+        raise ValueError("this family reads an untied LM head")
+    return Shape(family=config["family"], **dec.sizes(config))
+
+
+def program_sizes(s):
+    return dict(dec.program_sizes(s), tie_embeddings=False)
+
+
+def leaves(s):
+    return dec.leaves(s) + [(("head", "w"), (s.d_model, s.vocab_rows),
+                             weights.BF16, "normal", s.d_model ** -0.5)]
+
+
+hidden = dec.hidden
+
+
+def head(params, s):
+    return params["head"]["w"][:, :s.vocab].T.astype(F32)
+
+
+non_embedding_params = dec.non_embedding_params
+head_params = dec.head_params
+decode_token_flops = dec.decode_token_flops
+prefill_flops = dec.prefill_flops
+
+
+def weight_read_bytes(s):
+    # neither the table nor the padded head; the head over the real vocabulary
+    rows = 2 * s.vocab_rows * s.d_model * 2
+    return weights.nbytes(s) - rows + head_params(s) * 2
+
+
+def decode_step_bytes(s, step):
+    return weight_read_bytes(s) + sum(step.ctxs) * dec.kv_bytes_per_token(s)
+'''
+
 BACKLOG = {
     "arrivals": {"kind": "backlog", "fill_round": 16}, "slots": 3,
     "cache_len": 96,
@@ -67,6 +136,10 @@ def write_root(tmp: Path, config=DECODER, mix=BACKLOG, mix_name="tiny-mix",
     chip = tmp / "bench"
     for sub in ("configs", "traffic", "metrics"):
         (chip / sub).mkdir(parents=True, exist_ok=True)
+    shutil.copytree(family.HERE / "families", chip / "families",
+                    ignore=shutil.ignore_patterns("__pycache__"),
+                    dirs_exist_ok=True)
+    (chip / "families" / "tiny-untied.py").write_text(UNTIED_FAMILY)
     (chip / "configs" / f"{config['name']}.json").write_text(json.dumps(config))
     (chip / "traffic" / f"{mix_name}.json").write_text(json.dumps(mix))
     (chip / "metrics" / "admitted_per_step.py").write_text(READER)

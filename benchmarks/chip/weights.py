@@ -4,6 +4,8 @@ The benchmark makes the weights itself, in the parameter layout the program
 serves (``models/model.py``: layer-stacked leaves), so that the reference can
 make the very same ones from the seed without importing the program.  The
 harness checks the layout against the program's ``init`` before it serves.
+Each family lists its leaves (``chip.family``); drawing them is the same for
+every family.
 
 Each leaf is drawn in float32 from ``fold_in(key, leaf index)`` and cast to
 its served type in one jitted call.  Scales follow the program's own
@@ -19,6 +21,7 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
+from chip import family
 from chip.shapes import Shape
 
 BF16, F32 = "bfloat16", "float32"
@@ -27,56 +30,19 @@ BF16, F32 = "bfloat16", "float32"
 Leaf = Tuple[Tuple[str, ...], Tuple[int, ...], str, str, float]
 
 
-def _decoder_leaves(s: Shape) -> List[Leaf]:
-    L, d, hd = s.layers, s.d_model, s.head_dim
-    q, kv = s.heads * hd, s.kv_heads * hd
-    blk = ("stack", "dense_stack")
-    leaves = [
-        (blk + ("ln1", "scale"), (L, d), BF16, "norm", 0.0),
-        (blk + ("ln2", "scale"), (L, d), BF16, "norm", 0.0),
-        (blk + ("attn", "wq", "w"), (L, d, q), BF16, "normal", d ** -0.5),
-        (blk + ("attn", "wk", "w"), (L, d, kv), BF16, "normal", d ** -0.5),
-        (blk + ("attn", "wv", "w"), (L, d, kv), BF16, "normal", d ** -0.5),
-        (blk + ("attn", "wo", "w"), (L, q, d), BF16, "normal", q ** -0.5),
-        (blk + ("mlp", "wi", "w"), (L, d, s.d_ff), BF16, "normal", d ** -0.5),
-        (blk + ("mlp", "wg", "w"), (L, d, s.d_ff), BF16, "normal", d ** -0.5),
-        (blk + ("mlp", "wo", "w"), (L, s.d_ff, d), BF16, "normal",
-         s.d_ff ** -0.5),
-    ]
-    if s.qk_norm:
-        leaves += [(blk + ("attn", "qnorm", "scale"), (L, hd), BF16, "norm", 0.),
-                   (blk + ("attn", "knorm", "scale"), (L, hd), BF16, "norm", 0.)]
-    return leaves
-
-
-def _mamba2_leaves(s: Shape) -> List[Leaf]:
-    L, d, H = s.layers, s.d_model, s.ssm_heads
-    blk = ("stack", "ssm_stack")
-    m = blk + ("mamba",)
+def final_norm_and_embed(s: Shape) -> List[Leaf]:
+    """The leaves that follow the layer stack: the final norm and the
+    embedding table (``vocab_rows`` rows)."""
     return [
-        (blk + ("ln", "scale"), (L, d), BF16, "norm", 0.0),
-        (m + ("in_proj", "w"), (L, d, s.in_proj_dim), BF16, "normal",
-         d ** -0.5),
-        (m + ("conv_w",), (L, s.d_conv, s.conv_dim), BF16, "normal",
-         s.d_conv ** -0.5),
-        (m + ("conv_b",), (L, s.conv_dim), BF16, "normal", 0.1),
-        (m + ("a_log",), (L, H), F32, "a_log", 0.0),
-        (m + ("d_skip",), (L, H), F32, "norm", 0.0),
-        (m + ("dt_bias",), (L, H), F32, "dt_bias", 0.0),
-        (m + ("norm", "scale"), (L, s.d_inner), BF16, "norm", 0.0),
-        (m + ("out_proj", "w"), (L, s.d_inner, d), BF16, "normal",
-         s.d_inner ** -0.5),
+        (("final_norm", "scale"), (s.d_model,), BF16, "norm", 0.0),
+        (("embed", "table"), (s.vocab_rows, s.d_model), BF16, "normal",
+         s.d_model ** -0.5),
     ]
 
 
 def leaves(s: Shape) -> List[Leaf]:
     """Every parameter leaf of the served pytree, in a fixed order."""
-    body = _decoder_leaves(s) if s.family == "decoder" else _mamba2_leaves(s)
-    return body + [
-        (("final_norm", "scale"), (s.d_model,), BF16, "norm", 0.0),
-        (("embed", "table"), (s.vocab_rows, s.d_model), BF16, "normal",
-         s.d_model ** -0.5),
-    ]
+    return family.of(s).leaves(s)
 
 
 def _draw(key, shape, kind: str, scale: float):

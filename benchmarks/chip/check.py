@@ -10,6 +10,19 @@ correct when the widest gap is at most the configuration's limit.
 
 ``control`` reads the same gaps for the tokens that the float8 control would
 have picked at the same positions: the limit has to separate the two.
+
+Routed models.  A top-k router is a discrete choice: where the reference's
+expert scores lie close together, the served precision's rounding flips
+which experts a token takes, and the logits there move by far more than
+rounding.  A configuration whose family marks routing near-ties
+(``hidden_ties``, ``chip.family``) sets two numbers under ``check``, each
+with its ``_why``: ``route_margin``, the margin delta within which the
+reference's own choice of an expert held on this chip counts as a near-tie,
+and ``max_route_tie_share``, the largest share of the served positions that
+may be so marked.  The widest gap is then read over the positions left
+unmarked, for the served tokens and the control's picks alike, at the same
+limit, and the marked share is compared with its own limit.  Without the
+keys every served position is compared, as above.
 """
 from __future__ import annotations
 
@@ -25,6 +38,8 @@ from chip import family
 from chip.shapes import Shape
 
 HEAD_CHUNK = 128                # positions per block of LM-head logits
+ROUTE_KEYS = ("route_margin", "route_margin_why", "max_route_tie_share",
+              "max_route_tie_share_why")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,11 +79,38 @@ def pack(reqs: Sequence[Served], length: int):
     return tokens, targets, mask
 
 
-@functools.partial(jax.jit, static_argnames=("s", "control"))
-def _gaps(params, tokens, targets, mask, s: Shape, control: bool):
+def route_margin(limits: dict, s: Shape) -> Optional[float]:
+    """The margin delta that a configuration's ``check`` (``limits``) sets, or
+    None where it sets none.  Refuses part of the rule without the rest,
+    and a margin for a family that marks no ties."""
+    given = [k for k in ROUTE_KEYS if k in limits]
+    if not given:
+        return None
+    if len(given) < len(ROUTE_KEYS):
+        raise ValueError(f"check: {given} need "
+                         f"{[k for k in ROUTE_KEYS if k not in given]} too")
+    if not hasattr(family.of(s), "hidden_ties"):
+        raise ValueError(f"check: route_margin is set, but the family "
+                         f"{s.family!r} marks no routing ties (no "
+                         f"hidden_ties)")
+    margin, share = float(limits["route_margin"]), \
+        float(limits["max_route_tie_share"])
+    if not (margin > 0 and 0 <= share < 1):
+        raise ValueError(f"check: route_margin {margin} must be above 0 and "
+                         f"max_route_tie_share {share} in [0, 1)")
+    return margin
+
+
+@functools.partial(jax.jit, static_argnames=("s", "control", "margin"))
+def _gaps(params, tokens, targets, mask, s: Shape, control: bool,
+          margin: Optional[float] = None):
     with jax.default_matmul_precision("highest"):
         fam = family.of(s)
-        h_ref = fam.hidden(params, tokens, s)
+        if margin is None:
+            h_ref, ties = fam.hidden(params, tokens, s), None
+        else:
+            h_ref, ties = fam.hidden_ties(params, tokens, s, margin)
+            mask = mask & ~ties
         h_ctl = fam.hidden(params, tokens, s, quant=True) if control else h_ref
         table = fam.head(params, s)                        # (V, d) float32
         b, t, d = h_ref.shape
@@ -92,28 +134,36 @@ def _gaps(params, tokens, targets, mask, s: Shape, control: bool):
         _, (gap, cgap) = jax.lax.scan(
             body, None, (blocks(h_ref), blocks(h_ctl), blocks(targets),
                          blocks(mask)))
-        return gap.swapaxes(0, 1).reshape(b, t), cgap.swapaxes(0, 1).reshape(b, t)
+        return (gap.swapaxes(0, 1).reshape(b, t),
+                cgap.swapaxes(0, 1).reshape(b, t), ties)
 
 
 @dataclasses.dataclass(frozen=True)
 class Gaps:
-    max_gap: float              # widest gap of the served tokens
+    max_gap: float              # widest gap of the served tokens (unmarked)
     control_gap: Optional[float]  # widest gap of the float8 control's picks
-    tokens: int                 # served tokens compared
+    tokens: int                 # served tokens (positions) in the sample
+    ties: int                   # of them, marked as routing near-ties
     requests: int
+
+    @property
+    def tie_share(self) -> Optional[float]:
+        return self.ties / self.tokens if self.tokens else None
 
 
 def gaps(params, reqs: Sequence[Served], s: Shape, length: int,
-         control: bool = False) -> Gaps:
+         control: bool = False, margin: Optional[float] = None) -> Gaps:
     """Reference gaps of ``reqs`` (padded to ``length``, a multiple of
-    ``HEAD_CHUNK``) under the served weights ``params``."""
+    ``HEAD_CHUNK``) under the served weights ``params``; with ``margin``,
+    over the positions the family does not mark as routing near-ties."""
     tokens, targets, mask = pack(reqs, length)
-    gap, cgap = _gaps(params, jnp.asarray(tokens), jnp.asarray(targets),
-                      jnp.asarray(mask), s, control)
+    gap, cgap, ties = _gaps(params, jnp.asarray(tokens), jnp.asarray(targets),
+                            jnp.asarray(mask), s, control, margin)
     gap, cgap = np.asarray(gap), np.asarray(cgap)
+    ties = 0 if ties is None else int((np.asarray(ties) & mask).sum())
     return Gaps(max_gap=float(gap.max()),
                 control_gap=float(cgap.max()) if control else None,
-                tokens=int(mask.sum()), requests=len(reqs))
+                tokens=int(mask.sum()), ties=ties, requests=len(reqs))
 
 
 def reference_length(mix: dict) -> int:

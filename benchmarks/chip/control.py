@@ -10,7 +10,9 @@ requests, reading two widest gaps: that of the tokens the program served
 (the lower reading, as every run reads it) and that of the tokens the
 float8 control would pick at the same positions (the upper reading, which
 the limit has to stay below).  Each is judged by the harness's own
-comparison: the program's ``correct`` has to come out true and the
+comparison, over the same positions (those not marked as routing near-ties,
+where the configuration sets ``route_margin``) and with the same limit on
+the marked share: the program's ``correct`` has to come out true and the
 control's false.  Not run by the benchmark's own runs.
 """
 import time
@@ -40,9 +42,9 @@ def main() -> int:
                                     time.perf_counter())
         g = harness.judge(cell, seed, served, control=True)
         line = {"workload": a.workload, "seed": seed, "tokens": g.tokens,
-                "requests": g.requests}
+                "ties": g.ties, "requests": g.requests}
         for who, gap in (("program", g.max_gap), ("control", g.control_gap)):
-            correct, numbers = harness.compare(cell, gap)
+            correct, numbers = harness.compare(cell, gap, g.tie_share)
             line[who] = {"correct": correct, "check": numbers}
         print(json.dumps(line), flush=True)
     return 0

@@ -20,6 +20,34 @@ anywhere else.  A family module provides:
   ``prefill_flops(s, tokens)`` and ``decode_step_bytes(s, step)``, where
   ``step`` is the harness's ``chip.layer.Step`` record.
 
+A family with routed layers (a top-k router over experts, some of them
+held on this chip) may also provide
+
+* ``hidden_ties(params, tokens, shape, margin) -> (hidden, ties)``: the
+  states ``hidden`` gives and, from the same float32 pass (not a second
+  forward), a (B, T) boolean: True where, in any routed layer, the
+  reference's own choice of an expert held on this chip lies within
+  ``margin`` of changing, at the group boundary of a grouped router and at
+  the top-k boundary (``chip.reference.topk_near`` finds both).  A score
+  that sums n selection keys (a group's two best) is compared at n times
+  the margin.  Where the router normalises the weights over the chosen
+  experts, a held expert's weight also jumps when a group near its
+  boundary changes the chosen set while a held expert is chosen: mark
+  that too.
+
+Its configuration turns the marks on with four keys under ``check``:
+``route_margin`` (delta, the margin for two selection keys), sized on the
+chip from the spread of the router's selection keys, the served program's
+against the float32 reference's over the check's sample: a choice flips
+only where two keys lie closer than their two errors together, so delta is
+at least twice the widest error, with room; ``max_route_tie_share`` (the
+largest share of served positions that may be marked, above what sound
+runs read); and ``route_margin_why`` and ``max_route_tie_share_why`` (the
+readings each was set from).  The check then compares every unmarked
+position at the unchanged ``max_logit_gap``, and the marked share at its
+own limit (``chip.check``).  A family without routed layers provides
+nothing more, and its configuration sets none of these keys.
+
 A family may reuse another's pieces by loading its file:
 ``family.load(Path(__file__).with_name("decoder.py"))``.
 """

@@ -384,6 +384,7 @@ def prepare(workload: str, root: Path = cells.REPO_ROOT):
     """Resolve the cell, insist on its chips, turn on the compile cache."""
     from repro.launch.compile_cache import enable_compile_cache
     cell = cells.resolve(workload, root)
+    check.route_margin(cell.config["check"], cell.shape)  # refuse it now
     dev = require_chips(cell.chips)
     peaks = counts.peaks(dev["kind"])
     cache_dir = enable_compile_cache()
@@ -480,19 +481,32 @@ def measure(cell: Cell, dev: dict, peaks: dict, seed: int, seconds: float,
 def judge(cell: Cell, seed: int, served, control: bool = False) -> check.Gaps:
     """The reference's reading of the served sample, on weights made anew."""
     t = time.perf_counter()
+    margin = check.route_margin(cell.config["check"], cell.shape)
     gaps = check.gaps(weights.make(cell.shape, seed), served, cell.shape,
-                      check.reference_length(cell.mix), control=control)
+                      check.reference_length(cell.mix), control=control,
+                      margin=margin)
+    ties = "" if margin is None else \
+        f" ({gaps.ties} marked as routing near-ties within {margin})"
     log(f"reference over {gaps.requests} requests, {gaps.tokens} served "
-        f"tokens: {time.perf_counter() - t:.3f}s")
+        f"tokens{ties}: {time.perf_counter() - t:.3f}s")
     return gaps
 
 
-def compare(cell: Cell, gap: Optional[float]):
+def compare(cell: Cell, gap: Optional[float],
+            tie_share: Optional[float] = None):
     """``correct`` and the numbers compared, each beside its limit: the
-    widest gap is at most the configuration's limit.  No reading fails."""
-    limit = float(cell.config["check"]["max_logit_gap"])
-    return (gap is not None and gap <= limit,
-            {"max_logit_gap": {"value": gap, "limit": limit}})
+    widest gap is at most the configuration's limit and, where the
+    configuration sets ``route_margin`` (``chip.check``), the share of
+    served positions marked as routing near-ties is at most its own.  No
+    reading fails."""
+    limits = cell.config["check"]
+    numbers = {"max_logit_gap": {
+        "value": gap, "limit": float(limits["max_logit_gap"])}}
+    if "route_margin" in limits:
+        numbers["route_tie_share"] = {
+            "value": tie_share, "limit": float(limits["max_route_tie_share"])}
+    return (all(n["value"] is not None and n["value"] <= n["limit"]
+                for n in numbers.values()), numbers)
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool,
@@ -500,8 +514,11 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     """Run one cell and return its result line (the contract's object)."""
     cell, dev, peaks = prepare(workload, root)
     result, served = measure(cell, dev, peaks, seed, seconds, trace, started)
-    gap = judge(cell, seed, served).max_gap if served else None
-    result["correct"], result["check"] = compare(cell, gap)
+    gap = share = None
+    if served:
+        g = judge(cell, seed, served)
+        gap, share = g.max_gap, g.tie_share
+    result["correct"], result["check"] = compare(cell, gap, share)
     for name, c in result["check"].items():
         log(f"check {name} {c['value']!r} limit {c['limit']!r}")
     return result

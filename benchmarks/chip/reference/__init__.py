@@ -10,6 +10,9 @@ is applied in blocks by ``chip.check``.
 ``quant=True`` is the benchmark's control: every matrix product takes its
 inputs rounded to float8 e4m3 (per-tensor scale for weights, per-row for
 activations), the precision below the served bfloat16.
+
+``topk_near`` is for a family with routed layers: a router's top-k choice
+and where it lies within a margin of changing (``chip.family``).
 """
 from __future__ import annotations
 
@@ -40,3 +43,20 @@ def rmsnorm(x, scale, eps):
 
 def upcast(tree):
     return jax.tree.map(lambda a: a.astype(F32), tree)
+
+
+def topk_near(keys, k: int, margin: float):
+    """A top-``k`` choice over the last axis of ``keys`` and where it is
+    within ``margin`` of changing: ``(chosen, near)``, booleans like
+    ``keys``.  An entry is near when it is chosen less than ``margin``
+    above the largest entry left out, or left out less than ``margin``
+    below the smallest entry chosen.  ``-inf`` keys (masked) are never
+    near.  For a grouped router, call it once on the group scores (the
+    group boundary) and once on the keys of the groups kept (the top-k
+    boundary)."""
+    top, idx = jax.lax.top_k(keys, k + 1)
+    chosen = jnp.any(jax.nn.one_hot(idx[..., :k], keys.shape[-1],
+                                    dtype=bool), axis=-2)
+    lo, hi = top[..., k - 1:k], top[..., k:k + 1]
+    near = jnp.where(chosen, keys - hi < margin, lo - keys < margin)
+    return chosen, near

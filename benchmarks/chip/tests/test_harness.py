@@ -102,6 +102,10 @@ def test_sound_run_is_correct(tmp_path, on_cpu, case):
     assert list(r)[-1] == "check"
     gap = r["check"]["max_logit_gap"]
     assert gap["value"] <= gap["limit"]
+    # no routing-tie rule in the configuration: the gap alone is compared
+    assert r["check"] == {"max_logit_gap": {
+        "value": gap["value"],
+        "limit": CASES[case][0]["check"]["max_logit_gap"]}}
     assert r["attempted"] > 0 and r["failed"] == 0
     assert r["metrics"]["setup_s"]["value"] > 0
     assert r["device"]["count"] == 1
@@ -207,6 +211,9 @@ def test_float8_control_reads_wider_than_the_program(tmp_path, on_cpu, case):
     assert g.max_gap <= limit < g.control_gap
     assert harness.compare(cell, g.max_gap)[0] is True
     assert harness.compare(cell, g.control_gap)[0] is False
+    assert g.ties == 0
+    assert harness.compare(cell, g.max_gap, g.tie_share) == \
+        harness.compare(cell, g.max_gap)
 
 
 def test_sweep_finds_a_growing_queue(tmp_path, on_cpu):

@@ -15,7 +15,11 @@ One chip, in one process:
       with a float32 run under highest matmul precision (``COS_MIN``);
   (d) one ``decode_step`` through ``Backend("pallas")`` on the Server's
       caches matches the XLA backend, and its compiled text holds the
-      ``ame_gemm`` kernel as a ``tpu_custom_call``.
+      ``ame_gemm`` kernel as a ``tpu_custom_call``;
+  (e) the Server's own decode program holds the Pallas decode-attention
+      kernel as a ``tpu_custom_call``, and the kernel's read of one layer
+      of the Server's caches matches ``attention.decode_attention`` on
+      that layer's slice.
 
 ``--chips 4`` runs ``launch.steps.make_train_step`` on a (data=2, model=2)
 mesh of four chips with FSDP, compares step 0's loss with ``lm.loss_fn`` on
@@ -42,6 +46,8 @@ import numpy as np  # noqa: E402
 
 from repro.configs import get  # noqa: E402
 from repro.configs.base import ShapeSpec  # noqa: E402
+from repro.kernels import decode_attention as decode_kernel  # noqa: E402
+from repro.models import attention  # noqa: E402
 from repro.models import model as lm  # noqa: E402
 from repro.models.layers import PALLAS, Backend  # noqa: E402
 from repro.serve.loop import Request, Server  # noqa: E402
@@ -244,6 +250,50 @@ def check_pallas_decode(cfg, params, caches, positions, *,
     return rep
 
 
+def check_decode_kernel(cfg, srv, *, interpret: bool = False,
+                        seed: int = 0) -> dict:
+    """(e) Unless ``interpret``, the Server's decode program must hold the
+    Pallas decode-attention kernel as a ``tpu_custom_call``.  The kernel's
+    read of the last layer of the Server's caches, each row at its last
+    written position, must match ``attention.decode_attention`` on that
+    layer's slice (cosine ``COS_MIN`` per row).  Interpreting, on a
+    configuration the kernel does not serve, it reads whole-cache blocks."""
+    block = attention.decode_kv_block(cfg, srv.cache_len, "tpu")
+    has_kernel = None
+    if not interpret:
+        check(block > 0, f"{cfg.name}: the decode-attention kernel does not "
+                         f"serve this configuration on a TPU")
+        toks = jnp.zeros((srv.slots, 1), jnp.int32)
+        text = srv._decode.lower(srv.params, toks, jnp.asarray(srv.pos),
+                                 srv.caches).compile().as_text()
+        has_kernel = any('custom_call_target="tpu_custom_call"' in line
+                         and "decode_attention" in line
+                         for line in text.splitlines())
+        check(has_kernel, "the Server's decode program holds no "
+                          "decode_attention tpu_custom_call")
+    cache = srv.caches["dense_stack"]
+    layer = cache["k"].shape[0] - 1
+    q_pos = jnp.asarray(np.maximum(srv.pos - 1, 0), jnp.int32)
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal(
+        (srv.slots, cfg.n_heads, cfg.head_dim_)), jnp.bfloat16)
+    got = decode_kernel.decode_attention(
+        q, cache["k"], cache["v"], cache["pos"], layer, q_pos,
+        block=block or min(decode_kernel.DEFAULT_BLOCK, srv.cache_len),
+        interpret=interpret)
+    want = jax.jit(lambda q, k, v, pos, qp: attention.decode_attention(
+        q, k[layer], v[layer], q_pos=qp, kv_pos=pos[layer]))(
+        q, cache["k"], cache["v"], cache["pos"], q_pos)
+    got, want = np.asarray(got), np.asarray(want)
+    rep = {"cosine_min": min(cosine(g, w) for g, w in zip(got, want)),
+           "max_abs": float(np.abs(got - want).max()), "layer": layer,
+           "block": block, "tpu_custom_call": has_kernel}
+    check(rep["cosine_min"] >= COS_MIN,
+          f"decode-attention kernel vs decode_attention: cosine "
+          f"{rep['cosine_min']:.6f} < {COS_MIN}")
+    return rep
+
+
 def peak_bytes(dev=None):
     stats = (dev or jax.devices()[0]).memory_stats()
     return stats.get("peak_bytes_in_use") if stats else None
@@ -291,6 +341,13 @@ def run_one_chip(seed: int, log: CompileLog) -> None:
           f"{pal['max_abs']:.4e}, argmax equal {pal['argmax_equal']}/"
           f"{pal['slots']}, tpu_custom_call {pal['tpu_custom_call']}",
           flush=True)
+    with log.phase("decode_kernel"):
+        dk = check_decode_kernel(cfg, srv, seed=seed)
+    print(f"[smoke] decode-attention kernel vs decode_attention (layer "
+          f"{dk['layer']}, block {dk['block']}): min cosine "
+          f"{dk['cosine_min']:.6f} (min {COS_MIN}), max abs "
+          f"{dk['max_abs']:.4e}, in the Server's decode program "
+          f"{dk['tpu_custom_call']}", flush=True)
     print(f"[smoke] peak_bytes_in_use {peak_bytes()}", flush=True)
 
 

@@ -4,9 +4,12 @@ Memory-linear by construction: training/prefill attention is a chunked
 online-softmax scan over KV blocks (the pure-jnp twin of the Pallas flash
 kernel — same math, lowered by XLA for the dry-run), so 32k prefill never
 materializes a T x T score matrix.  Decode (one new token per row) writes
-that token into the layer-stacked cache in place and attends over the
-layer's slice as it is stored (``decode_attention``): no chunk-major or
-float32 copy of the cache.
+that token into the layer-stacked cache in place.  On a TPU, where the
+input allows it (``decode_kv_block``), the Pallas kernel
+``kernels.decode_attention`` then reads the stacked cache in place, each
+row only up to its query position; everywhere else ``decode_attention``
+attends over the layer's slice as it is stored: no chunk-major or float32
+copy of the cache.
 
 Sharding posture (single/multi-pod mesh): q heads shard on 'model'; KV
 tensors shard on heads when divisible, else on head_dim (partial scores are
@@ -22,11 +25,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kernels import decode_attention as decode_kernel
 from repro.models.layers import (
     Backend, XLA, apply_norm, dense, dense_init, norm_init, out_constrain,
     rope,
 )
-from repro.sharding.context import constrain
+from repro.sharding.context import constrain, current_mesh
 
 NEG = -1e30
 
@@ -157,6 +161,28 @@ def decode_attention(q, k, v, *, q_pos, kv_pos, window: int = 0):
     return out.reshape(b, h, -1)
 
 
+def decode_kv_block(cfg: ArchConfig, cache_len: int, platform: str,
+                    mesh=None) -> int:
+    """Positions per block that ``kernels.decode_attention`` reads when it
+    serves the decode read on ``platform``; 0 where ``decode_attention``
+    (XLA) serves it.
+
+    The kernel reads a row only up to its query position, which holds
+    where slot == position: no sliding window and a plain (not MLA) cache.
+    It also needs heads of whole 128-lane rows, 1 or a multiple of 8 KV
+    heads (then the position-major view of the cache is a bitcast, not a
+    copy), a cache of whole blocks, and no mesh axis over 1.
+    """
+    if (platform != "tpu" or cfg.attention_free or cfg.mla is not None
+            or cfg.sliding_window or cfg.head_dim_ % 128
+            or not (cfg.n_kv_heads == 1 or cfg.n_kv_heads % 8 == 0)):
+        return 0
+    if mesh is not None and any(n > 1 for n in mesh.shape.values()):
+        return 0
+    block = min(decode_kernel.DEFAULT_BLOCK, cache_len)
+    return block if cache_len % block == 0 else 0
+
+
 # ---------------------------------------------------------------------------
 # standard GQA attention module
 # ---------------------------------------------------------------------------
@@ -243,8 +269,8 @@ def attention_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
                                 q_offset=positions[:, 0])
     else:
         # decode: write the new kv at [layer, row, slot] of the stacked
-        # cache, attend over the layer's slice
-        from repro.sharding.context import current_mesh
+        # cache, then read it: in place by the Pallas kernel on a TPU where
+        # decode_kv_block allows, else over the layer's slice
         mesh = current_mesh()
         msize = mesh.shape.get("model", 1) if mesh else 1
         heads_shardable = hkv % max(msize, 1) == 0
@@ -261,21 +287,34 @@ def attention_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
                 "pos": cache["pos"].at[layer, bi, slot].set(
                     pos.astype(jnp.int32)),
             }
-        kk, vv = new_cache["k"][layer], new_cache["v"][layer]
-        if heads_shardable:
-            kk = constrain(kk, "batch", None, "model", None)
-            vv = constrain(vv, "batch", None, "model", None)
+
+        def layer_slice():
+            qq = q
+            kk, vv = new_cache["k"][layer], new_cache["v"][layer]
+            if heads_shardable:
+                kk = constrain(kk, "batch", None, "model", None)
+                vv = constrain(vv, "batch", None, "model", None)
+            else:
+                # KV heads don't divide the model axis: shard head_dim on
+                # both q and kv so the score contraction is over the
+                # sharded dim — a small all-reduce of (B,H,Tk) partials
+                # instead of cache all-gathers
+                qq = constrain(qq, "batch", None, None, "model")
+                kk = constrain(kk, "batch", None, None, "model")
+                vv = constrain(vv, "batch", None, None, "model")
+            return decode_attention(qq[:, 0], kk, vv, q_pos=pos,
+                                    kv_pos=new_cache["pos"][layer],
+                                    window=cfg.sliding_window)
+
+        block = decode_kv_block(cfg, clen, "tpu", mesh)
+        if block:
+            out = jax.lax.platform_dependent(
+                tpu=lambda: decode_kernel.decode_attention(
+                    q[:, 0], new_cache["k"], new_cache["v"],
+                    new_cache["pos"], layer, pos, block=block),
+                default=layer_slice)
         else:
-            # KV heads don't divide the model axis: shard head_dim on both
-            # q and kv so the score contraction is over the sharded dim —
-            # a small all-reduce of (B,H,Tk) partials instead of cache
-            # all-gathers
-            q = constrain(q, "batch", None, None, "model")
-            kk = constrain(kk, "batch", None, None, "model")
-            vv = constrain(vv, "batch", None, None, "model")
-        out = decode_attention(q[:, 0], kk, vv, q_pos=pos,
-                               kv_pos=new_cache["pos"][layer],
-                               window=cfg.sliding_window)
+            out = layer_slice()
         out = out[:, None].astype(q.dtype)
     out = constrain(out, "batch", None, "model", None)
     y = dense(p["wo"], out.reshape(b, t, h * hd), backend)

@@ -64,12 +64,15 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.isa import PIM_FREQ_HZ
 from repro.models import model as lm
+from repro.kernels.decode_attention import blocks_read
+from repro.models.attention import decode_kv_block
 from repro.obs.metrics import Histogram
 from repro.obs.spans import span
 from repro.runtime.cluster import HostLinkLedger
 from repro.serve.offload import DecodeOffload
 from repro.serve.traffic import (SLO, HostCostModel, SimClock, Trace,
                                  TraceRequest, WallClock)
+from repro.sharding.context import current_mesh
 
 
 class AdmissionError(RuntimeError):
@@ -324,19 +327,37 @@ class Server:
                     (req.finished_at - req.first_token_at)
                     / (len(req.out_tokens) - 1))
 
-    def _kv_positions(self, live: List[int]) -> Tuple[int, int]:
-        """Cache positions of one decode step: (live, scanned).  Live is
-        what the ``live`` slots have written, ``pos + 1`` each; scanned is
-        what the decode program attends over, every position of every
-        slot's cache (``attention.decode_attention`` masks dead slots, it
-        does not skip them).
+    def _decode_positions(self, live: List[int]) -> np.ndarray:
+        """Each slot's position for the decode program: ``pos`` for the
+        ``live`` slots, 0 for free ones, whose token and cache row nothing
+        reads (the next admission splices the whole row)."""
+        pos = np.zeros_like(self.pos)
+        pos[live] = self.pos[live]
+        return pos
+
+    def _kv_positions(self, live: List[int],
+                      platform: str) -> Tuple[int, int]:
+        """Cache positions of one decode step on ``platform``: (live,
+        scanned).  Live is what the ``live`` slots have written, ``pos +
+        1`` each; scanned is what the decode program reads.  Where
+        ``attention.decode_kv_block`` engages the Pallas kernel, that is
+        the blocks ``kernels.decode_attention.blocks_read`` counts at each
+        slot's decode position (one block for a free slot); elsewhere
+        every position of every slot's cache (``attention.decode_attention``
+        masks dead slots, it does not skip them).
         An SSM has no positions to attend over."""
         if self.cfg.family == "ssm":
             return 0, 0
         per_slot = min(self.cache_len, self.cfg.sliding_window) \
             if self.cfg.sliding_window else self.cache_len
         kv_live = sum(min(int(self.pos[i]) + 1, per_slot) for i in live)
-        return kv_live, self.slots * per_slot
+        block = decode_kv_block(self.cfg, self.cache_len, platform,
+                                current_mesh())
+        if not block:
+            return kv_live, self.slots * per_slot
+        nblk = blocks_read(self._decode_positions(live), block,
+                           self.cache_len)
+        return kv_live, int(nblk.sum()) * block
 
     def step(self):
         """One serving iteration: fire serve faults, admit, batched
@@ -360,12 +381,12 @@ class Server:
         toks = np.zeros((self.slots, 1), np.int32)
         for i in live:
             toks[i, 0] = self.active[i].out_tokens[-1]
-        kv_live, kv_scanned = self._kv_positions(live)
+        kv_live, kv_scanned = self._kv_positions(live, jax.default_backend())
         with span("server.decode", live=len(live), kv_live=kv_live,
                   kv_scanned=kv_scanned):
             logits, self.caches = self._decode(
                 self.params, jnp.asarray(toks),
-                jnp.asarray(self.pos), self.caches)
+                jnp.asarray(self._decode_positions(live)), self.caches)
         if self.metrics is not None:
             m = self.metrics
             m.counter("serve.decode_steps", unit="steps",

@@ -28,8 +28,9 @@ def run(args, env=CPU_ENV, cwd=ROOT, timeout=300):
 
 
 def test_smoke_phases_on_reduced_config():
-    """Serve, the first-token, decoded-token and precision checks, and the
-    Pallas-vs-XLA decode on the Server's caches, at bf16 compute."""
+    """Serve, the first-token, decoded-token and precision checks, the
+    Pallas-vs-XLA decode and the decode-attention kernel's read on the
+    Server's caches, at bf16 compute."""
     cfg = get("qwen3-1.7b").reduced().with_policy(compute_dtype="bfloat16")
     params = chip_smoke.init_params(cfg, seed=0)
     srv, done, wall = chip_smoke.serve(
@@ -48,6 +49,9 @@ def test_smoke_phases_on_reduced_config():
         backend=Backend("pallas", interpret=True))
     assert pal["slots"] == 2 and pal["cosine_min"] >= chip_smoke.COS_MIN
     assert not pal["tpu_custom_call"]  # interpreted, not compiled
+    dk = chip_smoke.check_decode_kernel(cfg, srv, interpret=True)
+    assert dk["cosine_min"] >= chip_smoke.COS_MIN and dk["max_abs"] < 0.1
+    assert dk["tpu_custom_call"] is None  # interpreted, not compiled
 
 
 def test_checks_fail_on_a_wrong_result():

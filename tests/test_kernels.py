@@ -184,3 +184,167 @@ def test_ssd4_vs_recurrence():
     want = jax.vmap(jax.vmap(ref.ssd_scan))(x, log_a, bb, cc)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# decode attention over the layer-stacked cache, read in place
+# ---------------------------------------------------------------------------
+
+
+def _stacked_cache(layers, rows, slots, hkv, d, q_pos, rng):
+    """bf16 K/V (L,B,S,Hkv,D) and positions (L,B,S), slot == position up to
+    each row's q_pos, -1 past it; a hole (-1) in every longer row.  Returns
+    the clean cache and one with garbage wherever the mask drops a slot:
+    pos -1, or a valid-looking position past q_pos."""
+    shape = (layers, rows, slots, hkv, d)
+    k = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    slot = np.arange(slots)
+    pos = np.where(slot[None, :] <= q_pos[:, None], slot[None, :], -1)
+    pos[q_pos > 4, 3] = -1                              # unwritten hole
+    past = rng.integers(1, 5, rows)                     # past q_pos, valid-
+    for b, n in enumerate(past):                        # looking positions
+        end = min(q_pos[b] + 1 + n, slots)
+        pos[b, q_pos[b] + 1:end] = slot[q_pos[b] + 1:end]
+    pos = np.broadcast_to(pos, (layers, rows, slots)).astype(np.int32)
+    drop = jnp.asarray((pos < 0) | (pos > q_pos[None, :, None]))[..., None,
+                                                                  None]
+    junk = jnp.asarray(rng.standard_normal(shape) * 1e4, jnp.bfloat16)
+    return (k, v, jnp.where(drop, junk, k), jnp.where(drop, -junk, v),
+            jnp.asarray(pos))
+
+
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("hkv,d,g", [
+    (8, 128, 1),        # MHA, head_dim 128
+    (8, 128, 2),        # qwen3-1.7b: GQA 16 q / 8 kv heads
+    (1, 256, 8),        # gemma-2b: MQA, head_dim 256
+])
+def test_decode_attention_kernel_vs_oracle(hkv, d, g, layer):
+    """The Pallas decode read of one layer of the stacked cache against
+    ``models.attention.decode_attention`` on that layer's slice.  One batch
+    mixes q_pos 0, block - 1, block, block + 1 and S - 1 with random
+    lengths; garbage where the mask drops a slot must not move the output."""
+    from repro.kernels.decode_attention import decode_attention
+    from repro.models.attention import decode_attention as oracle
+    layers, slots, block = 2, 512, 128
+    rng = np.random.default_rng(hkv * 1000 + d + g)
+    q_pos = np.array([0, block - 1, block, block + 1, slots - 1,
+                      *rng.integers(0, slots, 3)], np.int32)
+    k, v, k_junk, v_junk, pos = _stacked_cache(layers, len(q_pos), slots,
+                                               hkv, d, q_pos, rng)
+    q = randn(len(q_pos), hkv * g, d, dtype=jnp.bfloat16)
+    li = 0 if layer == "first" else layers - 1
+    qp = jnp.asarray(q_pos)
+    got = decode_attention(q, k_junk, v_junk, pos, li, qp, block=block,
+                           interpret=True)
+    want = oracle(q, k[li], v[li], q_pos=qp, kv_pos=pos[li])
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-2, rtol=1e-2)
+
+
+def _decode_cfg(**kw):
+    from repro.configs import get
+    return get("qwen3-1.7b").reduced().replace(
+        n_heads=16, n_kv_heads=8, head_dim=128, n_layers=1, **kw)
+
+
+@pytest.mark.parametrize("case,block", [
+    ("eligible", 256),
+    ("cpu", 0),
+    ("window", 0),
+    ("mla", 0),
+    ("head_dim_80", 0),
+    ("kv_heads_2", 0),
+    ("model_axis_2", 0),
+    ("data_axis_2", 0),
+    ("ragged_cache", 0),
+])
+def test_decode_kv_block_rule(case, block):
+    """The one rule for the Pallas decode read: TPU only, and only where
+    the input allows it; everything else keeps the XLA read."""
+    from repro.configs import get
+    from repro.models.attention import decode_kv_block
+    cfg, platform, mesh, cache_len = _decode_cfg(), "tpu", None, 2048
+    if case == "cpu":
+        platform = "cpu"
+    elif case == "window":
+        cfg = cfg.replace(sliding_window=1024)
+    elif case == "mla":
+        cfg = get("deepseek-v3-671b")
+    elif case == "head_dim_80":
+        cfg = cfg.replace(head_dim=80)
+    elif case == "kv_heads_2":
+        cfg = cfg.replace(n_kv_heads=2)
+    elif case == "model_axis_2":
+        mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
+    elif case == "data_axis_2":
+        mesh = jax.sharding.AbstractMesh((2, 1), ("data", "model"))
+    elif case == "ragged_cache":
+        cache_len = 1000
+    assert decode_kv_block(cfg, cache_len, platform, mesh) == block
+    one = jax.sharding.AbstractMesh((1, 1), ("data", "model"))
+    assert decode_kv_block(_decode_cfg(), 2048, "tpu", one) == 256
+    assert decode_kv_block(_decode_cfg(), 128, "tpu") == 128
+
+
+@pytest.mark.parametrize("arch", ["gemma-2b", "phi4-mini-3.8b",
+                                  "command-r-35b", "internvl2-76b",
+                                  "qwen3-1.7b"])
+def test_decode_kv_block_engages_for_full_attention_configs(arch):
+    from repro.configs import get
+    from repro.models.attention import decode_kv_block
+    assert decode_kv_block(get(arch), 2048, "tpu") == 256
+
+
+@pytest.mark.parametrize("arch", ["mixtral-8x22b", "deepseek-v3-671b",
+                                  "zamba2-2.7b", "mamba2-370m"])
+def test_decode_kv_block_keeps_xla_for_other_configs(arch):
+    from repro.configs import get
+    from repro.models.attention import decode_kv_block
+    assert decode_kv_block(get(arch), 2048, "tpu") == 0
+
+
+def test_server_counts_what_the_decode_read_scans():
+    """``Server._kv_positions``: with the rule engaged, each slot's pos + 1
+    rounded up to the block, live or not; otherwise every position."""
+    from repro.serve.loop import Server
+    from repro.models import model as lm
+    cfg = _decode_cfg()
+    srv = Server(cfg, lm.init(cfg, jax.random.key(0)), slots=3,
+                 cache_len=512)
+    srv.pos[:] = [0, 255, 300]
+    live = [1, 2]
+    assert srv._kv_positions(live, "tpu") == (256 + 301, 256 + 256 + 512)
+    assert srv._kv_positions(live, "cpu") == (256 + 301, 3 * 512)
+    narrow = cfg.replace(head_dim=64)
+    wide = Server(narrow, lm.init(narrow, jax.random.key(0)), slots=3,
+                  cache_len=512)
+    wide.pos[:] = srv.pos
+    assert wide._kv_positions(live, "tpu") == (256 + 301, 3 * 512)
+
+
+def test_free_slots_decode_at_position_zero():
+    """A free slot decodes at position 0, so the kernel reads one block of
+    it however far its last request ran."""
+    from repro.serve.loop import Server
+    from repro.models import model as lm
+    cfg = _decode_cfg()
+    srv = Server(cfg, lm.init(cfg, jax.random.key(0)), slots=3,
+                 cache_len=512)
+    srv.pos[:] = [400, 255, 300]
+    live = [1, 2]
+    assert list(srv._decode_positions(live)) == [0, 255, 300]
+    assert list(srv.pos) == [400, 255, 300]
+    assert srv._kv_positions(live, "tpu") == (256 + 301, 256 + 256 + 512)
+
+
+@pytest.mark.parametrize("q_pos,want", [(-1, 1), (0, 1), (127, 1), (128, 2),
+                                        (129, 2), (511, 4), (900, 4)])
+def test_blocks_read_host_and_traced_agree(q_pos, want):
+    from repro.kernels.decode_attention import blocks_read
+    host = blocks_read(np.array([q_pos]), 128, 512)
+    traced = jax.jit(lambda p: blocks_read(p, 128, 512))(jnp.array([q_pos]))
+    assert isinstance(host, np.ndarray) and int(host[0]) == want
+    assert int(traced[0]) == want

@@ -139,8 +139,9 @@ def _materialized_ops(hlo: str):
 def test_server_decode_updates_kv_cache_in_place_for_v5e(one_chip):
     """Full-width qwen3-1.7b decode as ``Server`` jits it (bfloat16 weights,
     24 slots x 2048, caches donated).  The step writes each layer's new
-    entries into the stacked cache and reads the layer's slice: no
-    temporary as large as one layer's K slice, and no copy, transpose or
+    entries into the stacked cache and the Pallas decode kernel reads the
+    stacked cache in place, once per layer-scan body: no temporary as
+    large as one layer's K slice, and no copy, transpose, dynamic-slice or
     dynamic-update-slice of the whole cache or of a layer's worth of it."""
     import dataclasses
     from repro.configs.base import get
@@ -169,8 +170,31 @@ def test_server_decode_updates_kv_cache_in_place_for_v5e(one_chip):
     layer_elems = k_layer.size // k_layer.shape[0]          # 24x2048x8x128
     layer_bytes = layer_elems * k_layer.dtype.itemsize      # 100663296
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
-    copies = [(name, dims, op) for name, dims, op in
-              _materialized_ops(compiled.as_text())
-              if op in ("copy", "transpose", "dynamic-update-slice")
+    hlo = compiled.as_text()
+    copies = [(name, dims, op) for name, dims, op in _materialized_ops(hlo)
+              if op in ("copy", "transpose", "dynamic-slice",
+                        "dynamic-update-slice")
               and math.prod(dims) in (layer_elems, k_layer.size)]
     assert not copies, copies
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1, kernels
+    assert "decode_attention" in kernels[0]
+    assert "/stack/while/body/" in kernels[0]
+
+
+@pytest.mark.parametrize("layers,rows,hkv,d,g", [
+    (28, 24, 8, 128, 2),         # qwen3-1.7b, 24 slots x 2048
+    (18, 8, 1, 256, 8),          # gemma-2b: MQA, head_dim 256
+])
+def test_decode_attention_kernel_compiles_for_v5e(one_chip, layers, rows,
+                                                  hkv, d, g):
+    from repro.kernels.decode_attention import decode_attention
+    slots = 2048
+    hlo = _compile_text(
+        decode_attention, one_chip, ((rows, hkv * g, d), BF16),
+        ((layers, rows, slots, hkv, d), BF16),
+        ((layers, rows, slots, hkv, d), BF16),
+        ((layers, rows, slots), jnp.int32), ((), jnp.int32),
+        ((rows,), jnp.int32))
+    assert "tpu_custom_call" in hlo

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Smoke run of the model stack on TPU v5e at qwen3-1.7b's full width.
 
-  python3 chip_smoke.py              # one chip: serve, check, Pallas decode
+  python3 chip_smoke.py              # one chip: serve, check, Pallas kernels
   python3 chip_smoke.py --chips 4    # four chips: the sharded train step only
 
 One chip, in one process:
@@ -13,9 +13,9 @@ One chip, in one process:
       of its prompt, one decoded token scores within ``DECODE_TOL`` of the
       best token of a prefill reference, and the bf16 prefill logits agree
       with a float32 run under highest matmul precision (``COS_MIN``);
-  (d) one ``decode_step`` through ``Backend("pallas")`` on the Server's
-      caches matches the XLA backend, and its compiled text holds the
-      ``ame_gemm`` kernel as a ``tpu_custom_call``;
+  (d) the paper's AME GEMM kernel (``kernels.ame_gemm``) compiles to a
+      ``tpu_custom_call`` and matches ``jnp.dot`` at the served MLP's up
+      projection, (24, d_model) @ (d_model, d_ff) in bfloat16;
   (e) the Server's own decode program holds the Pallas decode-attention
       kernel as a ``tpu_custom_call``, and the kernel's read of one layer
       of the Server's caches matches ``attention.decode_attention`` on
@@ -47,12 +47,13 @@ import numpy as np  # noqa: E402
 from repro.configs import get  # noqa: E402
 from repro.configs.base import ShapeSpec  # noqa: E402
 from repro.kernels import decode_attention as decode_kernel  # noqa: E402
+from repro.kernels.ame_gemm import ame_gemm  # noqa: E402
 from repro.models import attention  # noqa: E402
 from repro.models import model as lm  # noqa: E402
-from repro.models.layers import PALLAS, Backend  # noqa: E402
 from repro.serve.loop import Request, Server  # noqa: E402
 
 ARCH = "qwen3-1.7b"
+GEMM_ROWS = 24          # phase (d): the benchmark cell's 24 decode slots
 PROMPT_LENS = (128, 256, 512)   # _prefill_one compiles once per length
 COS_MIN = 0.99          # cosine similarity of two logit vectors
 DECODE_TOL = 0.1        # decoded token's logit >= best - DECODE_TOL * std
@@ -216,37 +217,34 @@ def check_precision(cfg, params, prompt, cache_len: int) -> dict:
     return rep
 
 
-# -- (d) the Pallas backend on the Server's caches -----------------------------
+# -- (d) the AME GEMM kernel at the served MLP's width ------------------------
 
 
-def check_pallas_decode(cfg, params, caches, positions, *,
-                        backend: Backend = PALLAS, seed: int = 0) -> dict:
-    """One decode step through ``backend`` against the XLA backend on the
-    same caches.  Unless the backend interprets its kernels, the compiled
-    program must hold ``ame_gemm`` as a Mosaic ``tpu_custom_call``."""
-    slots = positions.shape[0]
-    rng = np.random.default_rng(seed)
-    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (slots, 1)), jnp.int32)
-    pos = jnp.asarray(positions, jnp.int32)
-
-    def step(be):
-        return jax.jit(lambda p, t, ps, c: lm.decode_step(
-            p, t, ps, c, cfg, backend=be)[0])
-
-    compiled = step(backend).lower(params, toks, pos, caches).compile()
+def check_ame_gemm(cfg, rows: int, *, interpret: bool = False,
+                   seed: int = 0) -> dict:
+    """``ame_gemm`` with its default blocks against ``jnp.dot`` at the MLP
+    up projection of ``cfg``, (rows, d_model) @ (d_model, d_ff) in bfloat16,
+    operands made on the device.  Unless ``interpret``, the compiled
+    program must hold the kernel as a Mosaic ``tpu_custom_call``; each
+    output row must reach cosine ``COS_MIN``."""
+    ka, kb = jax.random.split(jax.random.PRNGKey(seed))
+    a = jax.random.normal(ka, (rows, cfg.d_model), jnp.bfloat16)
+    b = jax.random.normal(kb, (cfg.d_model, cfg.d_ff), jnp.bfloat16) \
+        * cfg.d_model ** -0.5
+    compiled = jax.jit(lambda x, w: ame_gemm(x, w, interpret=interpret)
+                       ).lower(a, b).compile()
     has_kernel = "tpu_custom_call" in compiled.as_text()
-    if not backend.interpret:
-        check(has_kernel, "the Pallas decode step holds no tpu_custom_call: "
+    if not interpret:
+        check(has_kernel, "the AME GEMM program holds no tpu_custom_call: "
                           "ame_gemm did not compile to a Mosaic kernel")
-    got = valid_logits(cfg, compiled(params, toks, pos, caches))
-    want = valid_logits(cfg, step(Backend("xla"))(params, toks, pos, caches))
+    got = np.asarray(compiled(a, b), np.float32)
+    want = np.asarray(jax.jit(jnp.dot)(a, b), np.float32)
     rep = {"cosine_min": min(cosine(g, w) for g, w in zip(got, want)),
            "max_abs": float(np.abs(got - want).max()),
-           "argmax_equal": int((got.argmax(-1) == want.argmax(-1)).sum()),
-           "slots": slots, "tpu_custom_call": has_kernel}
+           "shape": [rows, cfg.d_model, cfg.d_ff],
+           "tpu_custom_call": has_kernel}
     check(rep["cosine_min"] >= COS_MIN,
-          f"Pallas vs XLA decode logits: cosine {rep['cosine_min']:.6f} < "
-          f"{COS_MIN}")
+          f"ame_gemm vs jnp.dot: cosine {rep['cosine_min']:.6f} < {COS_MIN}")
     return rep
 
 
@@ -333,14 +331,13 @@ def run_one_chip(seed: int, log: CompileLog) -> None:
           f"{prec['rel_l2']:.4e}, max abs {prec['max_abs']:.4e}, argmax "
           f"equal {prec['argmax_equal']}", flush=True)
 
-    with log.phase("pallas_decode"):
-        pal = check_pallas_decode(cfg, params, srv.caches, srv.pos,
-                                  seed=seed)
-    print(f"[smoke] Pallas vs XLA decode logits ({pal['slots']} slots): "
-          f"min cosine {pal['cosine_min']:.6f} (min {COS_MIN}), max abs "
-          f"{pal['max_abs']:.4e}, argmax equal {pal['argmax_equal']}/"
-          f"{pal['slots']}, tpu_custom_call {pal['tpu_custom_call']}",
-          flush=True)
+    with log.phase("ame_gemm"):
+        gemm = check_ame_gemm(cfg, GEMM_ROWS, seed=seed)
+    m, k, n = gemm["shape"]
+    print(f"[smoke] ame_gemm vs jnp.dot ({m}x{k} @ {k}x{n} bf16): min "
+          f"cosine {gemm['cosine_min']:.6f} (min {COS_MIN}), max abs "
+          f"{gemm['max_abs']:.4e}, tpu_custom_call "
+          f"{gemm['tpu_custom_call']}", flush=True)
     with log.phase("decode_kernel"):
         dk = check_decode_kernel(cfg, srv, seed=seed)
     print(f"[smoke] decode-attention kernel vs decode_attention (layer "

@@ -72,15 +72,12 @@ class Policy:
     moment_dtype: str = "float32"   # adam m/v dtype: float32|bfloat16|int8
     factored_v: bool = False        # adafactor-style factored second moment
     sp: bool = False                # sequence-parallel residual stream
-    sp_rs: bool = False             # constrain block outputs seq-sharded
-                                    # (refuted iter-1: SPMD emits no RS here)
     remat_policy: str = "full"      # full | dots (save matmul outputs)
     tp_mode: str = "allreduce"      # 'allreduce' (megatron) | 'allgather'
                                     # ('allgather' = the paper's reduction-free
                                     #  dataflow at mesh level, DESIGN.md §3)
                                     # | 'ame_pim' (allgather specs + PIM
                                     #  cluster stack map, sharding.rules)
-    grad_compression: bool = False  # bf16+error-feedback cross-pod grad sync
 
 
 @dataclasses.dataclass(frozen=True)

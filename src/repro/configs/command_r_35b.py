@@ -16,7 +16,7 @@ COMMAND_R_35B = register(ArchConfig(
     attn_bias=False,
     tie_embeddings=True,
     policy=Policy(param_dtype="bfloat16", compute_dtype="bfloat16",
-                  fsdp=True, sp=True, microbatches=8, grad_compression=True,
+                  fsdp=True, sp=True, microbatches=8,
                   remat_policy="save_collectives"),
     source="hf:CohereForAI/c4ai-command-r-v01",
 ))

@@ -28,6 +28,6 @@ DEEPSEEK_V3_671B = register(ArchConfig(
     policy=Policy(param_dtype="bfloat16", compute_dtype="bfloat16",
                   fsdp=True, sp=True, microbatches=4, moment_dtype="int8",
                   remat_policy="save_collectives",
-                  factored_v=True, grad_compression=True),
+                  factored_v=True),
     source="arXiv:2412.19437",
 ))

@@ -21,7 +21,6 @@ INTERNVL2_76B = register(ArchConfig(
     modality="vision_text",
     policy=Policy(param_dtype="bfloat16", compute_dtype="bfloat16",
                   fsdp=True, sp=True, microbatches=8, moment_dtype="bfloat16",
-                  remat_policy="save_collectives",
-                  grad_compression=True),
+                  remat_policy="save_collectives"),
     source="arXiv:2404.16821",
 ))

@@ -23,7 +23,6 @@ MIXTRAL_8X22B = register(ArchConfig(
                   capacity_factor=1.25, sharding="tp"),
     policy=Policy(param_dtype="bfloat16", compute_dtype="bfloat16",
                   fsdp=True, sp=True, microbatches=8, moment_dtype="bfloat16",
-                  remat_policy="save_collectives",
-                  grad_compression=True),
+                  remat_policy="save_collectives"),
     source="arXiv:2401.04088",
 ))

@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels, each with its pure-jnp oracle in ref.py. A model layer
+# selects a kernel itself, from platform, shapes, config or mesh (see
+# models/attention.decode_kv_block); no caller-set option routes into one.

@@ -2,7 +2,7 @@
 
 Single pod:  (data=16, model=16) = 256 chips (TPU v5e pod).
 Multi-pod:   (pod=2, data=16, model=16) = 512 chips; the 'pod' axis is pure
-data parallelism over DCN with compressed gradient sync (optim/compression).
+data parallelism over DCN.
 
 A function, not a module-level constant: importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before any jax import).

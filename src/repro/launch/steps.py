@@ -15,7 +15,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeSpec, input_specs
 from repro.models import model as lm
-from repro.models.layers import XLA, Backend
 from repro.optim import adamw
 from repro.sharding import rules
 from repro.sharding.context import use_mesh
@@ -42,14 +41,14 @@ def _split_microbatches(batch: Dict, mb: int):
 
 def make_train_step(cfg: ArchConfig, mesh: Mesh, shape: ShapeSpec,
                     opt_cfg: adamw.AdamWConfig | None = None,
-                    backend: Backend = XLA, donate: bool = True):
+                    donate: bool = True):
     opt_cfg = opt_cfg or adamw.from_policy(cfg.policy)
     mb = cfg.policy.microbatches
     accum_dtype = (jnp.bfloat16 if cfg.policy.param_dtype == "bfloat16"
                    else jnp.float32)
 
     def loss_fn(p, b):
-        return lm.loss_fn(p, b, cfg, backend)
+        return lm.loss_fn(p, b, cfg)
 
     def train_step(params, opt_state, batch):
         with use_mesh(mesh):
@@ -100,14 +99,12 @@ def make_train_step(cfg: ArchConfig, mesh: Mesh, shape: ShapeSpec,
 # ---------------------------------------------------------------------------
 
 
-def make_prefill_step(cfg: ArchConfig, mesh: Mesh, shape: ShapeSpec,
-                      backend: Backend = XLA):
+def make_prefill_step(cfg: ArchConfig, mesh: Mesh, shape: ShapeSpec):
     cache_len = shape.seq_len
 
     def prefill_step(params, batch):
         with use_mesh(mesh):
-            return lm.prefill(params, batch, cfg, cache_len=cache_len,
-                              backend=backend)
+            return lm.prefill(params, batch, cfg, cache_len=cache_len)
 
     pshapes = abstract_params(cfg)
     bshapes = input_specs(cfg, shape)
@@ -136,14 +133,13 @@ def _prod(mesh, axes):
 
 
 def make_decode_step(cfg: ArchConfig, mesh: Mesh, shape: ShapeSpec,
-                     backend: Backend = XLA, donate: bool = True):
+                     donate: bool = True):
     cache_len = (min(shape.seq_len, cfg.sliding_window)
                  if cfg.sliding_window else shape.seq_len)
 
     def decode(params, tokens, positions, caches):
         with use_mesh(mesh):
-            return lm.decode_step(params, tokens, positions, caches, cfg,
-                                  backend=backend)
+            return lm.decode_step(params, tokens, positions, caches, cfg)
 
     b = shape.global_batch
     pshapes = abstract_params(cfg)
@@ -169,14 +165,10 @@ def make_decode_step(cfg: ArchConfig, mesh: Mesh, shape: ShapeSpec,
     return fn, shapes, (pspec, tspec, posspec, cspec)
 
 
-def make_step_for(cfg: ArchConfig, mesh: Mesh, shape: ShapeSpec,
-                  backend: Backend = XLA):
+def make_step_for(cfg: ArchConfig, mesh: Mesh, shape: ShapeSpec):
     """The step a given (arch x shape) cell lowers (train vs serve)."""
     if shape.kind == "train":
-        return ("train_step",) + make_train_step(cfg, mesh, shape,
-                                                 backend=backend)
+        return ("train_step",) + make_train_step(cfg, mesh, shape)
     if shape.kind == "prefill":
-        return ("prefill_step",) + make_prefill_step(cfg, mesh, shape,
-                                                     backend=backend)
-    return ("decode_step",) + make_decode_step(cfg, mesh, shape,
-                                               backend=backend)
+        return ("prefill_step",) + make_prefill_step(cfg, mesh, shape)
+    return ("decode_step",) + make_decode_step(cfg, mesh, shape)

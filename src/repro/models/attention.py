@@ -9,7 +9,13 @@ input allows it (``decode_kv_block``), the Pallas kernel
 ``kernels.decode_attention`` then reads the stacked cache in place, each
 row only up to its query position; everywhere else ``decode_attention``
 attends over the layer's slice as it is stored: no chunk-major or float32
-copy of the cache.
+copy of the cache.  ``decode_kv_positions`` counts what a decode step
+reads by the same rule, for the serving loop's spans and counters.
+
+Kernels are chosen here, in the layer that runs the op, from what it can
+observe: platform, shapes, config and mesh.  No caller sets a kernel.  A
+Pallas kernel serves an op only where its layer selects it so, and enters
+the model through a change that shows it winning in a benchmark cell.
 
 Sharding posture (single/multi-pod mesh): q heads shard on 'model'; KV
 tensors shard on heads when divisible, else on head_dim (partial scores are
@@ -27,8 +33,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.kernels import decode_attention as decode_kernel
 from repro.models.layers import (
-    Backend, XLA, apply_norm, dense, dense_init, norm_init, out_constrain,
-    rope,
+    apply_norm, dense, dense_init, norm_init, out_constrain, rope,
 )
 from repro.sharding.context import constrain, current_mesh
 
@@ -183,6 +188,30 @@ def decode_kv_block(cfg: ArchConfig, cache_len: int, platform: str,
     return block if cache_len % block == 0 else 0
 
 
+def decode_kv_positions(cfg: ArchConfig, cache_len: int, pos, live,
+                        platform: str) -> Tuple[int, int]:
+    """Cache positions one decode step attends on ``platform``: (live,
+    read).  ``pos`` (NumPy, one per slot) holds the positions the decode
+    program gets, ``live`` the slots that decode a request.  Live is what
+    those slots have written, ``pos + 1`` each (at most the window).  Read
+    is what the program reads: where ``decode_kv_block`` engages the Pallas
+    kernel (under the current mesh), the blocks
+    ``kernels.decode_attention.blocks_read`` counts at each slot's position,
+    free slots included; elsewhere every position of every slot's cache
+    (``decode_attention`` masks dead slots, it does not skip them).  An SSM
+    has no positions to attend over."""
+    if cfg.attention_free:
+        return 0, 0
+    per_slot = min(cache_len, cfg.sliding_window) \
+        if cfg.sliding_window else cache_len
+    kv_live = sum(min(int(pos[i]) + 1, per_slot) for i in live)
+    block = decode_kv_block(cfg, cache_len, platform, current_mesh())
+    if not block:
+        return kv_live, len(pos) * per_slot
+    nblk = decode_kernel.blocks_read(pos, block, cache_len)
+    return kv_live, int(nblk.sum()) * block
+
+
 # ---------------------------------------------------------------------------
 # standard GQA attention module
 # ---------------------------------------------------------------------------
@@ -221,7 +250,7 @@ def make_cache(cfg: ArchConfig, batch: int, length: int, dtype,
 
 
 def attention_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
-                    layer=None, backend: Backend = XLA, causal=True,
+                    layer=None, causal=True,
                     chunk: int = 1024) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """x (B,T,d).  Training/prefill: cache is None or gets filled.
     Decode: T==1, ``cache`` is the layer-stacked cache (L,B,S,...) and
@@ -229,9 +258,9 @@ def attention_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
     place (rolling for SWA) and the layer's slice is read."""
     b, t, d = x.shape
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
-    q = dense(p["wq"], x, backend).reshape(b, t, h, hd)
-    k = dense(p["wk"], x, backend).reshape(b, t, hkv, hd)
-    v = dense(p["wv"], x, backend).reshape(b, t, hkv, hd)
+    q = dense(p["wq"], x).reshape(b, t, h, hd)
+    k = dense(p["wk"], x).reshape(b, t, hkv, hd)
+    v = dense(p["wv"], x).reshape(b, t, hkv, hd)
     if cfg.qk_norm:
         q = apply_norm(p["qnorm"], q, cfg.norm_eps)
         k = apply_norm(p["knorm"], k, cfg.norm_eps)
@@ -317,7 +346,7 @@ def attention_apply(p, x, cfg: ArchConfig, *, positions, cache=None,
             out = layer_slice()
         out = out[:, None].astype(q.dtype)
     out = constrain(out, "batch", None, "model", None)
-    y = dense(p["wo"], out.reshape(b, t, h * hd), backend)
+    y = dense(p["wo"], out.reshape(b, t, h * hd))
     return out_constrain(y, cfg.policy), new_cache
 
 
@@ -357,7 +386,7 @@ def mla_make_cache(cfg: ArchConfig, batch: int, length: int, dtype,
 
 
 def mla_apply(p, x, cfg: ArchConfig, *, positions, cache=None, layer=None,
-              backend: Backend = XLA, chunk: int = 1024):
+              chunk: int = 1024):
     """Like ``attention_apply``: decode (T==1) writes into the layer-stacked
     latent cache at ``layer`` and reads the layer's slice."""
     m = cfg.mla
@@ -365,13 +394,13 @@ def mla_apply(p, x, cfg: ArchConfig, *, positions, cache=None, layer=None,
     h = cfg.n_heads
     nd, rd, vd = m.qk_nope_dim, m.qk_rope_dim, m.v_head_dim
 
-    q = dense(p["wuq"], apply_norm(p["qnorm"], dense(p["wdq"], x, backend),
-                                   cfg.norm_eps), backend)
+    q = dense(p["wuq"], apply_norm(p["qnorm"], dense(p["wdq"], x),
+                                   cfg.norm_eps))
     q = q.reshape(b, t, h, nd + rd)
     qn, qr = q[..., :nd], q[..., nd:]
     qr = rope(qr, positions, cfg.rope_theta)
-    ckv = apply_norm(p["kvnorm"], dense(p["wdkv"], x, backend), cfg.norm_eps)
-    kr = rope(dense(p["wkr"], x, backend)[:, :, None, :], positions,
+    ckv = apply_norm(p["kvnorm"], dense(p["wdkv"], x), cfg.norm_eps)
+    kr = rope(dense(p["wkr"], x)[:, :, None, :], positions,
               cfg.rope_theta)[:, :, 0]                        # shared head
 
     # absorbed form: fold W_uk into q, attend directly against the latent —
@@ -425,5 +454,5 @@ def mla_apply(p, x, cfg: ArchConfig, *, positions, cache=None, layer=None,
             q_offset=(positions[:, 0] if positions.ndim > 1 else positions))
     wuv = p["wuv"]["w"].astype(q.dtype).reshape(m.kv_lora_rank, h, vd)
     out = jnp.einsum("bthr,rhv->bthv", out, wuv)
-    y = dense(p["wo"], out.reshape(b, t, h * vd), backend)
+    y = dense(p["wo"], out.reshape(b, t, h * vd))
     return out_constrain(y, cfg.policy), new_cache

@@ -1,42 +1,23 @@
 """Shared neural layers, functional style: init(key,...) -> pytree,
-apply(params, x, ...) -> y.  All matmuls route through the backend so the
-paper's AME GEMM path is a first-class, swappable substrate."""
+apply(params, x, ...) -> y.
+
+Kernels are chosen in the layer that runs an op, from what it can observe
+(platform, shapes, config, mesh), never by a caller-set option: see
+``attention.decode_kv_block``, which sends the decode read to the Pallas
+kernel ``kernels.decode_attention`` on a TPU where the input allows it.  A
+Pallas kernel serves an op only where its layer selects it so, and enters
+the model through a change that shows it winning in a benchmark cell.  The
+paper's AME GEMM (``kernels.ame_gemm``) is a kernel of the repo, not a
+switch on the model: every matmul here is XLA's, accumulating in float32.
+"""
 from __future__ import annotations
 
-import dataclasses
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops
 from repro.sharding.context import constrain
-
-
-@dataclasses.dataclass(frozen=True)
-class Backend:
-    """Routes dense compute: 'xla' (einsum; used for dry-run lowering) or
-    'pallas' (the AME output-stationary kernels, compiled for the TPU).
-
-    ``interpret=True`` runs the Pallas kernels through the interpreter, for
-    CPU tests and rehearsals; it is never chosen from the device."""
-
-    mode: str = "xla"
-    interpret: bool = False
-
-    def matmul(self, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-        """(..., K) @ (K, N) with f32 accumulation."""
-        if self.mode == "pallas":
-            lead = x.shape[:-1]
-            x2 = x.reshape(-1, x.shape[-1])
-            return ops.gemm(x2, w, use_pallas=True, interpret=self.interpret,
-                            out_dtype=x.dtype).reshape(*lead, w.shape[-1])
-        return jnp.matmul(x, w, preferred_element_type=jnp.float32
-                          ).astype(x.dtype)
-
-
-XLA = Backend("xla")
-PALLAS = Backend("pallas")
 
 
 # -- dense -------------------------------------------------------------------
@@ -51,8 +32,10 @@ def dense_init(key, d_in: int, d_out: int, dtype, bias: bool = False,
     return p
 
 
-def dense(p, x, backend: Backend = XLA):
-    y = backend.matmul(x, p["w"].astype(x.dtype))
+def dense(p, x):
+    """(..., K) @ (K, N) with float32 accumulation, in the input's dtype."""
+    y = jnp.matmul(x, p["w"].astype(x.dtype),
+                   preferred_element_type=jnp.float32).astype(x.dtype)
     if "b" in p:
         y = y + p["b"].astype(x.dtype)
     return y
@@ -115,34 +98,26 @@ def out_constrain(y, policy):
     * allgather (the paper's reduction-free dataflow) and ame_pim (the
       PIM-cluster flavor sharing its mesh posture): stay feature-sharded
       on 'model' — no partial-sum reduction exists on the model axis.
-    * allreduce + SP: constrain straight to the seq-sharded residual layout
-      so SPMD emits a reduce-scatter (S link bytes) instead of all-reduce
-      (2S) followed by a slice.
     * allreduce: replicate => the Megatron all-reduce.
     """
     from repro.configs.base import OUTPUT_SHARDED_TP_MODES
     if policy.tp_mode in OUTPUT_SHARDED_TP_MODES:
         return constrain(y, "batch", None, "model")
-    if policy.sp and policy.sp_rs and y.ndim == 3 and y.shape[1] > 1:
-        return constrain(y, "batch", "model", None)
     return constrain(y, "batch", None, None)
 
 
-def mlp(p, x, act: str, backend: Backend = XLA, tp_mode: str = "allreduce",
-        policy=None):
-    """Gated/plain MLP.  Sharding posture depends on the TP dataflow —
-    see :func:`out_constrain`."""
-    from repro.configs.base import Policy
-    policy = policy or Policy(tp_mode=tp_mode)
-    h = dense(p["wi"], x, backend)
+def mlp(p, x, act: str, policy):
+    """Gated/plain MLP.  Sharding posture depends on the TP dataflow of
+    ``policy`` — see :func:`out_constrain`."""
+    h = dense(p["wi"], x)
     if act == "swiglu":
-        h = jax.nn.silu(dense(p["wg"], x, backend)) * h
+        h = jax.nn.silu(dense(p["wg"], x)) * h
     elif act == "geglu":
-        h = jax.nn.gelu(dense(p["wg"], x, backend)) * h
+        h = jax.nn.gelu(dense(p["wg"], x)) * h
     else:
         h = jax.nn.gelu(h)
     h = constrain(h, "batch", None, "model")
-    y = dense(p["wo"], h, backend)
+    y = dense(p["wo"], h)
     return out_constrain(y, policy)
 
 
@@ -156,7 +131,3 @@ def embed_init(key, vocab: int, d: int, dtype):
 def embed(p, tokens, compute_dtype):
     return p["table"].astype(compute_dtype)[tokens]
 
-
-def unembed(p, x, backend: Backend = XLA):
-    """Logits against the (possibly tied) embedding table."""
-    return backend.matmul(x, p["table"].astype(x.dtype).T)

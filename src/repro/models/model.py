@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models import transformer as tf
 from repro.models.layers import (
-    Backend, XLA, apply_norm, dense, dense_init, embed_init, norm_init,
+    apply_norm, dense, dense_init, embed_init, norm_init,
 )
 from repro.sharding.context import constrain
 
@@ -132,20 +132,18 @@ def _chunked_ce(h, head_w, targets, mask, cfg: ArchConfig,
     return loss, denom
 
 
-def loss_fn(params, batch: Dict, cfg: ArchConfig,
-            backend: Backend = XLA) -> Tuple[jnp.ndarray, Dict]:
+def loss_fn(params, batch: Dict, cfg: ArchConfig) -> Tuple[jnp.ndarray, Dict]:
     """Scalar training loss + metrics for any family/modality."""
     _, stack_apply = _family_fns(cfg)
     h, positions, off = _embed_inputs(params, batch, cfg)
     causal = not cfg.encoder_only
     h, _, aux = stack_apply(params["stack"], h, cfg, positions=positions,
-                            caches=None, backend=backend, causal=causal)
+                            caches=None, causal=causal)
     with jax.named_scope("head"):
-        return _head_loss(params, h, aux, off, batch, cfg, backend)
+        return _head_loss(params, h, aux, off, batch, cfg)
 
 
-def _head_loss(params, h, aux, off, batch: Dict, cfg: ArchConfig,
-               backend: Backend):
+def _head_loss(params, h, aux, off, batch: Dict, cfg: ArchConfig):
     """Final norm, LM head and cross-entropy (plus MTP) of ``loss_fn``."""
     h = apply_norm(params["final_norm"], h, cfg.norm_eps)
     cd = cfg.compute_dtype_()
@@ -169,7 +167,7 @@ def _head_loss(params, h, aux, off, batch: Dict, cfg: ArchConfig,
     if cfg.mtp:
         # multi-token prediction: predict t+2 from a projected hidden state
         h2 = apply_norm(params["mtp_norm"],
-                        dense(params["mtp_proj"], ht, backend), cfg.norm_eps)
+                        dense(params["mtp_proj"], ht), cfg.norm_eps)
         t2 = jnp.roll(targets, -1, axis=1)
         m2 = mask * (jnp.arange(targets.shape[1]) <
                      targets.shape[1] - 1).astype(jnp.float32)[None]
@@ -197,8 +195,8 @@ def make_caches(cfg: ArchConfig, batch: int, length: int):
     return tf.decoder_make_caches(cfg, batch, length, dtype)
 
 
-def prefill(params, batch: Dict, cfg: ArchConfig, cache_len: int,
-            backend: Backend = XLA) -> Tuple[jnp.ndarray, Any]:
+def prefill(params, batch: Dict, cfg: ArchConfig,
+            cache_len: int) -> Tuple[jnp.ndarray, Any]:
     """Encode the prompt, fill caches, return last-position logits."""
     _, stack_apply = _family_fns(cfg)
     h, positions, _ = _embed_inputs(params, batch, cfg)
@@ -206,8 +204,7 @@ def prefill(params, batch: Dict, cfg: ArchConfig, cache_len: int,
     caches = make_caches(cfg, b, cache_len)
     causal = not cfg.encoder_only
     h, caches, _ = stack_apply(params["stack"], h, cfg, positions=positions,
-                               caches=caches, backend=backend, causal=causal,
-                               remat=False)
+                               caches=caches, causal=causal, remat=False)
     return _logits(params, h, cfg), caches
 
 
@@ -223,8 +220,8 @@ def _logits(params, h, cfg: ArchConfig):
                          logits, -1e30)
 
 
-def decode_step(params, tokens, positions, caches, cfg: ArchConfig,
-                backend: Backend = XLA) -> Tuple[jnp.ndarray, Any]:
+def decode_step(params, tokens, positions, caches,
+                cfg: ArchConfig) -> Tuple[jnp.ndarray, Any]:
     """One token per sequence.  tokens (B,1) int32, positions (B,) int32."""
     _, stack_apply = _family_fns(cfg)
     cd = cfg.compute_dtype_()
@@ -234,8 +231,7 @@ def decode_step(params, tokens, positions, caches, cfg: ArchConfig,
         h = params["embed"]["table"].astype(cd)[tokens]      # (B,1,d)
     pos2 = positions[:, None]
     h, caches, _ = stack_apply(params["stack"], h, cfg, positions=pos2,
-                               caches=caches, backend=backend, causal=True,
-                               remat=False)
+                               caches=caches, causal=True, remat=False)
     return _logits(params, h, cfg), caches
 
 

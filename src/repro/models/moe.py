@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.models.layers import Backend, XLA, dense_init, mlp, mlp_init, out_constrain
+from repro.models.layers import dense_init, mlp, mlp_init, out_constrain
 from repro.sharding.context import constrain
 
 GROUP_SIZE = 256
@@ -60,8 +60,7 @@ def _group(s: int, target: int = GROUP_SIZE) -> int:
     return g
 
 
-def moe_apply(p, x, cfg: ArchConfig,
-              backend: Backend = XLA) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def moe_apply(p, x, cfg: ArchConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x (B,T,d) -> (y, aux_loss)."""
     m = cfg.moe
     b, t, d = x.shape
@@ -126,5 +125,5 @@ def moe_apply(p, x, cfg: ArchConfig,
     y = jnp.einsum("gsec,egcd->gsd", combine, out)
     y = y.reshape(b, t, d)
     if m.n_shared:
-        y = y + mlp(p["shared"], x, cfg.act, backend, policy=cfg.policy)
+        y = y + mlp(p["shared"], x, cfg.act, cfg.policy)
     return out_constrain(y, cfg.policy), aux

@@ -2,7 +2,8 @@
 the paper's outer-product accumulation (rank-1 updates into a resident
 accumulator; DESIGN.md §5).
 
-Train/prefill run the chunked SSD scan (kernels/ssd_scan or its jnp twin);
+Train/prefill run the chunked SSD scan (``kernels.ssd_scan.ssd_chunked_jnp4``,
+lowered by XLA; the Pallas ``ssd_scan`` serves no layer yet);
 decode advances the recurrence one step with O(1) state:
   conv_state (B, d_conv-1, conv_dim), ssm_state (B, H, N, P).
 """
@@ -14,8 +15,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.kernels import ops
-from repro.models.layers import Backend, XLA, apply_norm, dense, dense_init, norm_init, out_constrain
+from repro.kernels.ssd_scan import ssd_chunked_jnp4
+from repro.models.layers import (
+    apply_norm, dense, dense_init, norm_init, out_constrain,
+)
 from repro.sharding.context import constrain
 
 
@@ -66,15 +69,15 @@ def mamba_make_state(cfg: ArchConfig, batch: int, dtype,
     return {"conv": jnp.zeros(cs, dtype), "ssm": jnp.zeros(ss, jnp.float32)}
 
 
-def mamba_apply(p, u, cfg: ArchConfig, *, state: Optional[Dict] = None,
-                backend: Backend = XLA) -> Tuple[jnp.ndarray, Optional[Dict]]:
+def mamba_apply(p, u, cfg: ArchConfig, *, state: Optional[Dict] = None
+                ) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """u (B,T,d).  state=None: full-sequence scan (train/prefill).
     state given with T==1: single recurrent decode step."""
     s = cfg.ssm
     b, t, d = u.shape
     d_inner, nheads, conv_dim = _dims(cfg)
     g, n, hp = s.n_groups, s.d_state, s.head_dim
-    proj = dense(p["in_proj"], u, backend)
+    proj = dense(p["in_proj"], u)
     z, xc, bc, cc, dt = _split(cfg, proj)
     xbc = jnp.concatenate([xc, bc, cc], -1)                  # conv'd together
 
@@ -112,9 +115,8 @@ def mamba_apply(p, u, cfg: ArchConfig, *, state: Optional[Dict] = None,
                        None)
         c4 = constrain(jnp.repeat(cg, rep, 2).transpose(0, 2, 1, 3),
                        "batch", "model", None, None)
-        y = ops.ssd4(x4, la4.astype(jnp.float32), b4, c4,
-                     use_pallas=(backend.mode == "pallas"),
-                     interpret=backend.interpret, chunk=s.chunk)
+        y = ssd_chunked_jnp4(x4, la4.astype(jnp.float32), b4, c4,
+                             chunk=s.chunk)
         y = constrain(y, "batch", "model", None, None)
         y = y.transpose(0, 2, 1, 3)                            # (B,T,H,P)
         if state is not None:
@@ -146,5 +148,5 @@ def mamba_apply(p, u, cfg: ArchConfig, *, state: Optional[Dict] = None,
                              * xh)
     y = y.reshape(b, t, d_inner)
     y = apply_norm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
-    out = dense(p["out_proj"], y, backend)
+    out = dense(p["out_proj"], y)
     return out_constrain(out, cfg.policy), new_state

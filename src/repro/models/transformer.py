@@ -19,7 +19,7 @@ from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (
-    Backend, XLA, apply_norm, dense, dense_init, mlp, mlp_init, norm_init,
+    apply_norm, dense, dense_init, mlp, mlp_init, norm_init,
 )
 from repro.sharding.context import constrain
 
@@ -68,7 +68,7 @@ def block_init(key, cfg: ArchConfig, dtype, use_moe: bool):
 
 
 def block_apply(p, h, cfg: ArchConfig, *, positions, cache=None, layer=None,
-                backend: Backend = XLA, causal=True):
+                causal=True):
     # named scopes: each operation's op_name says which half of the block
     # it belongs to, in the compiled program and in a profiler trace
     with jax.named_scope("attn"):
@@ -76,21 +76,19 @@ def block_apply(p, h, cfg: ArchConfig, *, positions, cache=None, layer=None,
         if cfg.mla is not None:
             a, new_cache = attn_mod.mla_apply(p["attn"], x, cfg,
                                               positions=positions,
-                                              cache=cache, layer=layer,
-                                              backend=backend)
+                                              cache=cache, layer=layer)
         else:
             a, new_cache = attn_mod.attention_apply(
                 p["attn"], x, cfg, positions=positions, cache=cache,
-                layer=layer, backend=backend, causal=causal)
+                layer=layer, causal=causal)
         a = jax.ad_checkpoint.checkpoint_name(a, "blk_out")
         h = h + a
     with jax.named_scope("moe" if "moe" in p else "mlp"):
         x = apply_norm(p["ln2"], h, cfg.norm_eps)
         if "moe" in p:
-            y, aux = moe_mod.moe_apply(p["moe"], x, cfg, backend)
+            y, aux = moe_mod.moe_apply(p["moe"], x, cfg)
         else:
-            y, aux = mlp(p["mlp"], x, cfg.act, backend,
-                         policy=cfg.policy), jnp.float32(0)
+            y, aux = mlp(p["mlp"], x, cfg.act, cfg.policy), jnp.float32(0)
         h = h + jax.ad_checkpoint.checkpoint_name(y, "blk_out")
     if cfg.policy.sp and h.shape[1] > 1:
         # sequence-parallel residual stream: the per-layer saved residual
@@ -122,10 +120,10 @@ def _scan_in_place(fn, h, xs, caches):
     return h, caches, ys
 
 
-def _scan_blocks(params_stack, h, cfg, *, positions, caches, backend, causal,
+def _scan_blocks(params_stack, h, cfg, *, positions, caches, causal,
                  remat: bool):
     fn = functools.partial(block_apply, cfg=cfg, positions=positions,
-                           backend=backend, causal=causal)
+                           causal=causal)
     if caches is not None and h.shape[1] == 1:
         with jax.named_scope("stack"):
             h, new_caches, auxs = _scan_in_place(
@@ -177,7 +175,7 @@ def decoder_make_caches(cfg: ArchConfig, batch: int, length: int, dtype):
 
 
 def decoder_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
-                  backend: Backend = XLA, causal=True, remat=None):
+                  causal=True, remat=None):
     remat = cfg.policy.remat if remat is None else remat
     new_caches, aux = {}, jnp.float32(0)
     for name in ("dense_stack", "moe_stack"):
@@ -186,7 +184,7 @@ def decoder_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
         n = jax.tree_util.tree_leaves(p[name])[0].shape[0]
         cs = caches.get(name) if caches else _none_stack(n)
         h, nc, a = _scan_blocks(p[name], h, cfg, positions=positions,
-                                caches=cs, backend=backend, causal=causal,
+                                caches=cs, causal=causal,
                                 remat=remat and caches is None)
         if caches is not None:
             new_caches[name] = nc
@@ -217,15 +215,14 @@ def ssm_make_states(cfg: ArchConfig, batch: int, dtype):
 
 
 def ssm_stack_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
-                    backend: Backend = XLA, remat=None, **_):
+                    remat=None, **_):
     remat = cfg.policy.remat if remat is None else remat
 
     def body(carry, xs):
         lp, st = xs
         with jax.named_scope("ssm"):
             x = apply_norm(lp["ln"], carry, cfg.norm_eps)
-            y, ns = ssm_mod.mamba_apply(lp["mamba"], x, cfg, state=st,
-                                        backend=backend)
+            y, ns = ssm_mod.mamba_apply(lp["mamba"], x, cfg, state=st)
             return carry + y, ns
 
     if remat and caches is None:
@@ -289,7 +286,7 @@ def hybrid_make_caches(cfg: ArchConfig, batch: int, length: int, dtype):
 
 
 def hybrid_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
-                 backend: Backend = XLA, remat=None, **_):
+                 remat=None, **_):
     """Outer scan over groups; each group = ``shared_every`` mamba layers +
     one application of a shared attention block (round-robin over the
     distinct shared blocks, with per-application LoRA on its input proj)."""
@@ -310,8 +307,7 @@ def hybrid_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
         lp, st = xs
         with jax.named_scope("ssm"):
             x = apply_norm(lp["ln"], carry, cfg.norm_eps)
-            y, ns = ssm_mod.mamba_apply(lp["mamba"], x, cfg, state=st,
-                                        backend=backend)
+            y, ns = ssm_mod.mamba_apply(lp["mamba"], x, cfg, state=st)
             return carry + y, ns
 
     def group(hcur, kv, xs, layer):
@@ -325,8 +321,7 @@ def hybrid_apply(p, h, cfg: ArchConfig, *, positions, caches=None,
                 la.astype(cat.dtype) @ lb.astype(cat.dtype))
             xin = cat @ w
         y, nkv, _ = block_apply(sp["block"], xin, cfg, positions=positions,
-                                cache=kv, layer=layer, backend=backend,
-                                causal=True)
+                                cache=kv, layer=layer, causal=True)
         return hcur + (y - xin), nkv, gns   # residual on the block's delta
 
     def group_body(carry, xs_kv):

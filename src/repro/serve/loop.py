@@ -64,15 +64,13 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.isa import PIM_FREQ_HZ
 from repro.models import model as lm
-from repro.kernels.decode_attention import blocks_read
-from repro.models.attention import decode_kv_block
+from repro.models.attention import decode_kv_positions
 from repro.obs.metrics import Histogram
 from repro.obs.spans import span
 from repro.runtime.cluster import HostLinkLedger
 from repro.serve.offload import DecodeOffload
 from repro.serve.traffic import (SLO, HostCostModel, SimClock, Trace,
                                  TraceRequest, WallClock)
-from repro.sharding.context import current_mesh
 
 
 class AdmissionError(RuntimeError):
@@ -338,26 +336,11 @@ class Server:
     def _kv_positions(self, live: List[int],
                       platform: str) -> Tuple[int, int]:
         """Cache positions of one decode step on ``platform``: (live,
-        scanned).  Live is what the ``live`` slots have written, ``pos +
-        1`` each; scanned is what the decode program reads.  Where
-        ``attention.decode_kv_block`` engages the Pallas kernel, that is
-        the blocks ``kernels.decode_attention.blocks_read`` counts at each
-        slot's decode position (one block for a free slot); elsewhere
-        every position of every slot's cache (``attention.decode_attention``
-        masks dead slots, it does not skip them).
-        An SSM has no positions to attend over."""
-        if self.cfg.family == "ssm":
-            return 0, 0
-        per_slot = min(self.cache_len, self.cfg.sliding_window) \
-            if self.cfg.sliding_window else self.cache_len
-        kv_live = sum(min(int(self.pos[i]) + 1, per_slot) for i in live)
-        block = decode_kv_block(self.cfg, self.cache_len, platform,
-                                current_mesh())
-        if not block:
-            return kv_live, self.slots * per_slot
-        nblk = blocks_read(self._decode_positions(live), block,
-                           self.cache_len)
-        return kv_live, int(nblk.sum()) * block
+        scanned), as ``attention.decode_kv_positions`` counts them at the
+        positions the decode program gets."""
+        return decode_kv_positions(self.cfg, self.cache_len,
+                                   self._decode_positions(live), live,
+                                   platform)
 
     def step(self):
         """One serving iteration: fire serve faults, admit, batched

@@ -17,7 +17,6 @@ sys.path.insert(0, str(ROOT))
 
 import chip_smoke  # noqa: E402
 from repro.configs import get  # noqa: E402
-from repro.models.layers import Backend  # noqa: E402
 
 CPU_ENV = dict(os.environ, PYTHONPATH=str(ROOT / "src"), JAX_PLATFORMS="cpu")
 
@@ -28,9 +27,9 @@ def run(args, env=CPU_ENV, cwd=ROOT, timeout=300):
 
 
 def test_smoke_phases_on_reduced_config():
-    """Serve, the first-token, decoded-token and precision checks, the
-    Pallas-vs-XLA decode and the decode-attention kernel's read on the
-    Server's caches, at bf16 compute."""
+    """Serve, the first-token, decoded-token and precision checks at bf16
+    compute, ``ame_gemm`` at the reduced MLP's up projection, and the
+    decode-attention kernel's read on the Server's caches."""
     cfg = get("qwen3-1.7b").reduced().with_policy(compute_dtype="bfloat16")
     params = chip_smoke.init_params(cfg, seed=0)
     srv, done, wall = chip_smoke.serve(
@@ -44,11 +43,10 @@ def test_smoke_phases_on_reduced_config():
     prec = chip_smoke.check_precision(cfg, params, done[0].prompt, 64)
     assert chip_smoke.COS_MIN <= prec["cosine"] <= 1.0 + 1e-9
     assert prec["rel_l2"] > 0          # bf16 and f32 really differ
-    pal = chip_smoke.check_pallas_decode(
-        cfg, params, srv.caches, srv.pos,
-        backend=Backend("pallas", interpret=True))
-    assert pal["slots"] == 2 and pal["cosine_min"] >= chip_smoke.COS_MIN
-    assert not pal["tpu_custom_call"]  # interpreted, not compiled
+    gemm = chip_smoke.check_ame_gemm(cfg, srv.slots, interpret=True)
+    assert gemm["shape"] == [2, cfg.d_model, cfg.d_ff]
+    assert gemm["cosine_min"] >= chip_smoke.COS_MIN
+    assert not gemm["tpu_custom_call"]  # interpreted, not compiled
     dk = chip_smoke.check_decode_kernel(cfg, srv, interpret=True)
     assert dk["cosine_min"] >= chip_smoke.COS_MIN and dk["max_abs"] < 0.1
     assert dk["tpu_custom_call"] is None  # interpreted, not compiled

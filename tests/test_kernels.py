@@ -50,6 +50,18 @@ def test_ame_gemm_block_sweep(bm, bn, bk):
     allclose(got, ref.gemm(a, b), jnp.float32)
 
 
+@pytest.mark.parametrize("m,k,n", [
+    (32, 64, 96),       # tiny qwen3 MLP up projection, 2 x 16 tokens
+    (32, 96, 64),       # and its down projection
+])
+def test_ame_gemm_default_blocks_at_projection_shapes(m, k, n):
+    """The kernel with its default blocks, each clipped to a dimension
+    smaller than it, at a small model's MLP projections."""
+    a, b = randn(m, k, scale=0.3), randn(k, n, scale=0.3)
+    got = ame_gemm(a, b, interpret=True)
+    allclose(got, ref.gemm(a, b), jnp.float32)
+
+
 def test_ame_gemm_vmem_claim_fits():
     # default blocks must fit a v5e VMEM (~16 MiB per core) with headroom
     assert vmem_bytes() < 8 * 1024 * 1024
@@ -174,13 +186,13 @@ def test_ssd_chunked_jnp_vs_recurrence(bh, t, p, n, chunk):
 
 
 def test_ssd4_vs_recurrence():
-    from repro.kernels import ops
+    from repro.kernels.ssd_scan import ssd_chunked_jnp4
     b_, h_, t_, p_, n_ = 2, 3, 64, 8, 4
     x = randn(b_, h_, t_, p_, scale=0.5)
     log_a = -jnp.abs(randn(b_, h_, t_, dtype=jnp.float32, scale=0.2))
     bb = randn(b_, h_, t_, n_, scale=0.5)
     cc = randn(b_, h_, t_, n_, scale=0.5)
-    got = ops.ssd4(x, log_a, bb, cc, chunk=16)
+    got = ssd_chunked_jnp4(x, log_a, bb, cc, chunk=16)
     want = jax.vmap(jax.vmap(ref.ssd_scan))(x, log_a, bb, cc)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-3)
@@ -323,6 +335,22 @@ def test_server_counts_what_the_decode_read_scans():
                   cache_len=512)
     wide.pos[:] = srv.pos
     assert wide._kv_positions(live, "tpu") == (256 + 301, 3 * 512)
+
+
+@pytest.mark.parametrize("case,want", [
+    ("window", (100 + 128, 3 * 128)),
+    ("ssm", (0, 0)),
+])
+def test_decode_kv_positions_by_config(case, want):
+    """``attention.decode_kv_positions`` where the kernel does not serve
+    the read: a sliding window caps each slot's live and read positions at
+    the window; an SSM attends over no positions."""
+    from repro.configs import get
+    from repro.models.attention import decode_kv_positions
+    cfg = (_decode_cfg(sliding_window=128) if case == "window"
+           else get("mamba2-370m"))
+    pos = np.array([0, 99, 300])
+    assert decode_kv_positions(cfg, 512, pos, [1, 2], "tpu") == want
 
 
 def test_free_slots_decode_at_position_zero():

@@ -7,7 +7,6 @@ import pytest
 
 from repro.configs import SHAPES, all_names, applicable, get, input_specs
 from repro.models import model as lm
-from repro.models.layers import XLA
 
 RNG = np.random.default_rng(7)
 

@@ -1,6 +1,6 @@
 """Substrate tests: optimizer (incl. int8/factored moments), checkpointing
 (atomic/async/elastic), data determinism, fault-tolerant train loop
-(resume + preemption), serving loop, grad compression error feedback."""
+(resume + preemption), serving loop."""
 import json
 import os
 import signal
@@ -15,7 +15,7 @@ from repro.checkpoint.ckpt import CheckpointManager
 from repro.configs import SHAPES, get
 from repro.data.pipeline import PipelineState, SyntheticLM
 from repro.models import model as lm
-from repro.optim import adamw, compression
+from repro.optim import adamw
 from repro.serve.loop import Request, Server
 from repro.train.loop import LoopConfig, TrainLoop
 
@@ -98,24 +98,6 @@ def test_adamw_weight_decay_mask():
     p3, _, _ = adamw.apply(p, g, adamw.init(p, c2), c2)
     assert float(jnp.abs(p3["w"] - 1).max()) > 0        # decayed
     assert float(jnp.abs(p3["ln"]["scale"] - 1).max()) == 0  # masked
-
-
-# ---------------------------------------------------------------------------
-# gradient compression
-# ---------------------------------------------------------------------------
-
-
-def test_error_feedback_is_unbiased_over_time():
-    g = jnp.asarray(RNG.standard_normal((64,)) * 1e-3, jnp.float32)
-    ef = jnp.zeros_like(g, jnp.bfloat16)
-    total_q = jnp.zeros_like(g)
-    n = 50
-    for _ in range(n):
-        q, ef = compression.compress(g, ef)
-        total_q = total_q + q.astype(jnp.float32)
-    # sum of quantized payloads ~= sum of true grads (error fed back)
-    err = float(jnp.abs(total_q - n * g).max())
-    assert err < float(jnp.abs(g).max()) * 2.5
 
 
 # ---------------------------------------------------------------------------

@@ -261,8 +261,8 @@ def check_decode_kernel(cfg, srv, *, interpret: bool = False,
     if not interpret:
         check(block > 0, f"{cfg.name}: the decode-attention kernel does not "
                          f"serve this configuration on a TPU")
-        toks = jnp.zeros((srv.slots, 1), jnp.int32)
-        text = srv._decode.lower(srv.params, toks, jnp.asarray(srv.pos),
+        text = srv._decode.lower(srv.params, srv._tokens,
+                                 jnp.asarray(srv.pos),
                                  srv.caches).compile().as_text()
         has_kernel = any('custom_call_target="tpu_custom_call"' in line
                          and "decode_attention" in line

@@ -1,10 +1,19 @@
 """Batched serving loop: slot-based continuous batching.
 
 A fixed decode batch of ``slots``; finished sequences free their slot and
-the next queued request is prefilled into it.  Greedy sampling (argmax);
-the decode step is a single compiled function over the whole slot batch,
-caches donated in place — the production shape of vLLM-style serving,
-scaled to run on this host with reduced configs.
+the next queued request is prefilled into it.  Greedy sampling (argmax)
+inside the decode step, a single compiled function over the whole slot
+batch, caches donated in place — the production shape of vLLM-style
+serving, scaled to run on this host with reduced configs.
+
+The host runs one decode ahead of the device.  Each decode's next tokens
+stay on the device as the next decode's input; :meth:`Server.step`
+dispatches decode *n* and only then reads decode *n-1*'s tokens back, so
+the host's bookkeeping runs while the device decodes.  Retirements the
+host can predict (``max_new``, the cache's last position) free their slot
+before the dispatch; a row that ends by EOS, or that a fault knocks out,
+while its next decode is in flight has that token dropped on readback
+(``serve.discarded_tokens``).
 
 Pass ``pim_offload=DecodeOffload(cfg, ...)`` to mirror every decode
 step's matmuls onto a resident-weight PIM runtime (balanced placement,
@@ -91,10 +100,39 @@ class Request:
     done: bool = False
     submitted_at: float = 0.0
     admitted_at: float = 0.0        # left the queue (prefill started)
-    first_token_at: float = 0.0     # prefill produced the first token
+    first_token_at: float = 0.0     # the first token reached the host
     finished_at: float = 0.0
     retries: int = 0                # fault knock-outs survived so far
     not_before: int = 0             # earliest serving iteration to re-admit
+
+
+def decode_program(cfg: ArchConfig):
+    """The serving loop's jitted decode step, caches donated: one token per
+    slot in, the greedy next token of every slot out as an int32
+    ``(slots, 1)`` array (first index on ties), with the updated caches.
+
+    A free slot comes in at position 0 (``Server._decode_positions``) and
+    decodes token 0 whatever its row of ``t`` holds, so what a free row
+    carries never reaches another row (a routed layer's capacity)."""
+    def server_decode(p, t, ps, c):
+        t = jnp.where(ps[:, None] > 0, t, 0)
+        logits, c = lm.decode_step(p, t, ps, c, cfg)
+        return jnp.argmax(logits, -1, keepdims=True), c
+
+    return jax.jit(server_decode, donate_argnums=(3,))
+
+
+def server_first_token(tokens, logits, slot):
+    """Write the prefill's greedy token into row ``slot`` of the next
+    decode's input tokens."""
+    return tokens.at[slot, 0].set(jnp.argmax(logits[0]).astype(tokens.dtype))
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A dispatched decode whose tokens the host has not read back."""
+    tokens: jax.Array               # (slots, 1) int32, copying to the host
+    rows: Dict[int, Request]        # slot -> request the token belongs to
 
 
 class Server:
@@ -150,15 +188,19 @@ class Server:
                 key=lambda f: (f.at_iter, f.slot))
 
         # named functions, so that the device trace shows the programs as
-        # jit_server_decode(<hash>) and jit_server_prefill(<hash>)
-        def server_decode(p, t, ps, c):
-            return lm.decode_step(p, t, ps, c, cfg)
-
+        # jit_server_decode(<hash>), jit_server_prefill(<hash>) and
+        # jit_server_first_token(<hash>)
         def server_prefill(p, toks):
             return lm.prefill(p, {"tokens": toks}, cfg, cache_len=cache_len)
 
-        self._decode = jax.jit(server_decode, donate_argnums=(3,))
+        self._decode = decode_program(cfg)
         self._prefill_one = jax.jit(server_prefill)
+        self._first_token = jax.jit(server_first_token)
+        # each slot's next input token, on the device: the last decode's
+        # output with the first tokens of later admissions written in
+        self._tokens = jnp.zeros((slots, 1), jnp.int32)
+        # the decode whose tokens the host has not read back yet
+        self._in_flight: Optional[_InFlight] = None
 
     def _check_prompt(self, req: Request) -> None:
         """A prompt must leave at least one cache position for decode —
@@ -215,12 +257,11 @@ class Server:
             if f.slot >= self.slots or self.active[f.slot] is None:
                 continue
             req = self.active[f.slot]
-            self.active[f.slot] = None
             # the slot's cache is considered poisoned: restart the
             # request from its prompt (prefill re-runs on re-admission);
-            # its PIM-resident KV drops with it
-            if self._kv is not None:
-                self.pim_offload.kv_release(req.uid)
+            # its PIM-resident KV and its token in flight drop with it
+            self._free(f.slot)
+            self._drop_in_flight(f.slot)
             req.out_tokens = []
             req.first_token_at = 0.0
             req.retries += 1
@@ -280,22 +321,13 @@ class Server:
             self.caches = jax.tree.map(
                 lambda full, one: _splice(full, one, i, self.cfg),
                 self.caches, fresh)
-        # the host waits here for the prefill and the splice
+        # the prefill's argmax is the request's first token: it goes into
+        # this step's decode on the device, and reaches the host with the
+        # step's readback.  The virtual clock charges the host-prefill
+        # roofline (a WallClock ignores the advance and reads real time)
         with span("server.first_token"):
-            tok = int(jnp.argmax(logits[0]))
-        req.out_tokens.append(tok)
-        # the prefill's argmax IS the request's first token:
-        # TTFT closes here, before any decode step runs.  The
-        # virtual clock charges the host-prefill roofline (a
-        # WallClock ignores the advance and reads real time)
+            self._tokens = self._first_token(self._tokens, logits, i)
         self.clock.advance(self.cost.prefill_s(len(req.prompt)))
-        req.first_token_at = self.clock.now
-        if self.metrics is not None:
-            self.metrics.histogram(
-                "serve.ttft_s", unit="s",
-                help="time to first token (submit -> prefill "
-                     "argmax)").record(
-                req.first_token_at - req.submitted_at)
         self.active[i] = req
         self.pos[i] = len(req.prompt)
         # host prefill produced the prompt's KV: ship it onto
@@ -303,14 +335,40 @@ class Server:
         if self._kv is not None:
             self.pim_offload.kv_prefill(req.uid, len(req.prompt))
 
-    def _retire(self, i: int):
+    def _free(self, i: int):
+        """Empty slot ``i``; its request's PIM-resident KV goes with it."""
         req = self.active[i]
-        req.done = True
-        req.finished_at = self.clock.now
-        self.completed.append(req)
         self.active[i] = None
         if self._kv is not None:
             self.pim_offload.kv_release(req.uid)
+
+    def _drop_in_flight(self, i: int):
+        """Drop slot ``i``'s token from the decode in flight: its request
+        ended, or was knocked out, after that decode was dispatched."""
+        if self._in_flight is not None \
+                and self._in_flight.rows.pop(i, None) is not None:
+            if self.metrics is not None:
+                self.metrics.counter(
+                    "serve.discarded_tokens", unit="tokens",
+                    help="tokens decoded ahead for a request that had "
+                         "already ended (EOS or a fault)").inc()
+
+    def _free_finished(self):
+        """Free each slot whose request needs no further decode: with its
+        token in flight it has ``max_new`` tokens, or its next position is
+        the cache's last.  The request completes when that token is read
+        back."""
+        ahead = self._in_flight.rows if self._in_flight is not None else {}
+        for i, req in enumerate(self.active):
+            if req is not None and (
+                    len(req.out_tokens) + (i in ahead) >= req.max_new
+                    or int(self.pos[i]) >= self.cache_len - 1):
+                self._free(i)
+
+    def _complete(self, req: Request):
+        req.done = True
+        req.finished_at = self.clock.now
+        self.completed.append(req)
         if self.metrics is not None:
             m = self.metrics
             m.counter("serve.requests", unit="requests",
@@ -343,8 +401,10 @@ class Server:
                                    platform)
 
     def step(self):
-        """One serving iteration: fire serve faults, admit, batched
-        decode, retire; count the iteration against the step deadline."""
+        """One serving iteration: free the slots of requests whose last
+        token is in flight, fire serve faults, admit, dispatch the batched
+        decode, then read back the previous decode's tokens and retire;
+        count the iteration against the step deadline."""
         with span("server.step", iter=self._iter + 1):
             return self._step()
 
@@ -353,53 +413,32 @@ class Server:
             or self.step_deadline_s is not None
         t0 = time.time() if track_wall else 0.0
         self._iter += 1
+        # a request whose last token is in flight has finished decoding (the
+        # step that dispatched that token would have retired it, were the
+        # host not a decode ahead): faults and admissions see its slot free
+        self._free_finished()
         self._apply_serve_faults()
         self._admit()
         live = [i for i in range(self.slots) if self.active[i] is not None]
-        if not live:
+        behind, self._in_flight = self._in_flight, None
+        if not live and behind is None:
             # backing-off requests still count as pending work: report
             # True so run_until_drained keeps iterating toward their
             # re-admission instead of spinning the caller's loop exit
             return bool(self.queue)
-        toks = np.zeros((self.slots, 1), np.int32)
-        for i in live:
-            toks[i, 0] = self.active[i].out_tokens[-1]
-        kv_live, kv_scanned = self._kv_positions(live, jax.default_backend())
-        with span("server.decode", live=len(live), kv_live=kv_live,
-                  kv_scanned=kv_scanned):
-            logits, self.caches = self._decode(
-                self.params, jnp.asarray(toks),
-                jnp.asarray(self._decode_positions(live)), self.caches)
-        if self.metrics is not None:
-            m = self.metrics
-            m.counter("serve.decode_steps", unit="steps",
-                      help="batched decode steps").inc()
-            m.counter("serve.kv_live_positions", unit="positions",
-                      help="cache positions live slots had written, "
-                           "summed over decode steps").inc(kv_live)
-            m.counter("serve.kv_scanned_positions", unit="positions",
-                      help="cache positions decode attended over, "
-                           "summed over decode steps").inc(kv_scanned)
-        rec = None
-        if self.pim_offload is not None:
-            rec = self.pim_offload.step(
-                len(live),
-                request_ids=[self.active[i].uid for i in live])
-        # the decode iteration's virtual duration: the PIM step's clocked
-        # makespan when a sidecar ran it, else the host decode roofline
-        self.clock.advance(rec.pim_s if rec is not None
-                           else self.cost.decode_step_s(len(live)))
-        # the host waits here for the decode
-        with span("server.sample"):
-            nxt = np.asarray(jnp.argmax(logits, -1))
-        for i in live:
-            req = self.active[i]
-            req.out_tokens.append(int(nxt[i]))
-            self.pos[i] += 1
-            hit_eos = self.eos_id is not None and int(nxt[i]) == self.eos_id
-            if (len(req.out_tokens) >= req.max_new or hit_eos
-                    or int(self.pos[i]) >= self.cache_len - 1):
-                self._retire(i)
+        # a live request with no token yet was admitted in this step: its
+        # first token is in the decode's input
+        firsts = [i for i in live if not self.active[i].out_tokens]
+        inputs = self._tokens
+        if firsts:
+            inputs.copy_to_host_async()
+        step_s = self._dispatch(live, ahead=behind is not None) if live \
+            else 0.0
+        self._read_back(behind, firsts, inputs)
+        # the decode's virtual duration is charged once the tokens before
+        # it are read: in this order the device runs it while the host
+        # reads them
+        self.clock.advance(step_s)
         if track_wall:
             wall = time.time() - t0
             if self.step_deadline_s is not None \
@@ -415,6 +454,75 @@ class Server:
                     "serve.step_s", unit="s",
                     help="serving-iteration wall time").record(wall)
         return True
+
+    def _dispatch(self, live: List[int], ahead: bool) -> float:
+        """Dispatch the decode of the ``live`` slots on the device's tokens
+        and start the copy of its next tokens to the host.  ``ahead``: the
+        previous decode's tokens are not read back yet.  Returns the
+        decode's virtual duration: the PIM step's clocked makespan when a
+        sidecar ran it, else the host decode roofline."""
+        kv_live, kv_scanned = self._kv_positions(live, jax.default_backend())
+        with span("server.decode", live=len(live), kv_live=kv_live,
+                  kv_scanned=kv_scanned):
+            self._tokens, self.caches = self._decode(
+                self.params, self._tokens,
+                jnp.asarray(self._decode_positions(live)), self.caches)
+            self._tokens.copy_to_host_async()
+        self._in_flight = _InFlight(self._tokens,
+                                {i: self.active[i] for i in live})
+        self.pos[live] += 1
+        if self.metrics is not None:
+            m = self.metrics
+            m.counter("serve.decode_steps", unit="steps",
+                      help="batched decode steps").inc()
+            m.counter("serve.decode_ahead_steps", unit="steps",
+                      help="decode steps dispatched before the previous "
+                           "step's tokens were read back").inc(int(ahead))
+            m.counter("serve.kv_live_positions", unit="positions",
+                      help="cache positions live slots had written, "
+                           "summed over decode steps").inc(kv_live)
+            m.counter("serve.kv_scanned_positions", unit="positions",
+                      help="cache positions decode attended over, "
+                           "summed over decode steps").inc(kv_scanned)
+        if self.pim_offload is not None:
+            return self.pim_offload.step(
+                len(live),
+                request_ids=[self.active[i].uid for i in live]).pim_s
+        return self.cost.decode_step_s(len(live))
+
+    def _read_back(self, behind: Optional[_InFlight], firsts: List[int],
+                   inputs: jax.Array):
+        """Read the tokens of the decode ``behind`` and the first tokens of
+        this step's admissions (rows ``firsts`` of ``inputs``); append
+        them, apply EOS, and complete each request that has all its
+        tokens."""
+        # the host waits here for the decode before this step's, and for
+        # this step's prefills and splices
+        with span("server.sample"):
+            prev = np.asarray(behind.tokens) if behind is not None else None
+            first = np.asarray(inputs) if firsts else None
+        now = self.clock.now
+        for i in firsts:
+            req = self.active[i]
+            req.out_tokens.append(int(first[i, 0]))
+            req.first_token_at = now
+            if self.metrics is not None:
+                self.metrics.histogram(
+                    "serve.ttft_s", unit="s",
+                    help="time to first token (submit -> first token on "
+                         "the host)").record(now - req.submitted_at)
+        if behind is None:
+            return
+        for i, req in behind.rows.items():
+            tok = int(prev[i, 0])
+            req.out_tokens.append(tok)
+            if self.eos_id is not None and tok == self.eos_id:
+                if self.active[i] is req:       # decoding on: drop that
+                    self._free(i)
+                    self._drop_in_flight(i)
+                self._complete(req)
+            elif self.active[i] is not req:     # freed with this token
+                self._complete(req)
 
     def run_until_drained(self, max_iters: int = 10_000,
                           on_undrained: str = "raise"):
@@ -454,9 +562,9 @@ class Server:
         ``wall=True``).
 
         Computed from the request timestamps directly, so it works with
-        or without an attached metrics registry.  TTFT is submit ->
-        prefill argmax; TPOT divides the decode tail by the tokens after
-        the first (requests with a single token report no TPOT sample);
+        or without an attached metrics registry.  TTFT is submit -> the
+        first token on the host; TPOT divides the decode tail by the tokens
+        after the first (requests with a single token report no TPOT sample);
         queue delay is submit -> prefill start.
         """
         ttft = Histogram("serve.ttft_s", unit="s")

@@ -181,6 +181,14 @@ def test_programs_are_named(qwen3):
     cfg, params = qwen3
     srv, decode = compiled_decode(cfg, params)
     assert decode.as_text().startswith("HloModule jit_server_decode")
+    # the decode samples inside: each slot's next token and the caches out
+    nxt, caches = srv._decode.lower(
+        params, srv._tokens, jnp.zeros((SLOTS,), jnp.int32),
+        srv.caches).out_info
+    assert (nxt.shape, nxt.dtype) == ((SLOTS, 1), jnp.int32)
+    assert jax.tree.structure(caches) == jax.tree.structure(srv.caches)
+    assert [c.shape for c in jax.tree.leaves(caches)] == \
+        [c.shape for c in jax.tree.leaves(srv.caches)]
     prefill = srv._prefill_one.lower(
         params, jnp.zeros((1, 8), jnp.int32)).compile()
     assert prefill.as_text().startswith("HloModule jit_server_prefill")

@@ -137,15 +137,19 @@ def _materialized_ops(hlo: str):
 
 
 def test_server_decode_updates_kv_cache_in_place_for_v5e(one_chip):
-    """Full-width qwen3-1.7b decode as ``Server`` jits it (bfloat16 weights,
-    24 slots x 2048, caches donated).  The step writes each layer's new
-    entries into the stacked cache and the Pallas decode kernel reads the
-    stacked cache in place, once per layer-scan body: no temporary as
-    large as one layer's K slice, and no copy, transpose, dynamic-slice or
-    dynamic-update-slice of the whole cache or of a layer's worth of it."""
+    """Full-width qwen3-1.7b decode, the program ``Server._decode`` runs
+    (``loop.decode_program``: bfloat16 weights, 24 slots x 2048, caches
+    donated, the greedy next token taken inside).  It returns each slot's
+    next token as int32 (slots, 1) and the caches, no logits.  The step
+    writes each layer's new entries into the stacked cache and the Pallas
+    decode kernel reads the stacked cache in place, once per layer-scan
+    body: no temporary as large as one layer's K slice, and no copy,
+    transpose, dynamic-slice or dynamic-update-slice of the whole cache or
+    of a layer's worth of it."""
     import dataclasses
     from repro.configs.base import get
     from repro.models import model as lm
+    from repro.serve.loop import decode_program
     cfg = get("qwen3-1.7b")
     cfg = cfg.replace(policy=dataclasses.replace(cfg.policy,
                                                  param_dtype="bfloat16"))
@@ -161,11 +165,13 @@ def test_server_decode_updates_kv_cache_in_place_for_v5e(one_chip):
     toks = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
 
-    def server_decode(p, t, ps, c):
-        return lm.decode_step(p, t, ps, c, cfg)
-
-    compiled = jax.jit(server_decode, donate_argnums=(3,)).lower(
-        params, toks, pos, caches).compile()
+    lowered = decode_program(cfg).lower(params, toks, pos, caches)
+    nxt, out_caches = lowered.out_info
+    assert (nxt.shape, nxt.dtype) == ((slots, 1), jnp.int32)
+    assert jax.tree.map(lambda x: (x.shape, x.dtype), out_caches) == \
+        jax.tree.map(lambda x: (x.shape, x.dtype), caches)
+    compiled = lowered.compile()
+    assert compiled.as_text().startswith("HloModule jit_server_decode")
     k_layer = caches["dense_stack"]["k"]
     layer_elems = k_layer.size // k_layer.shape[0]          # 24x2048x8x128
     layer_bytes = layer_elems * k_layer.dtype.itemsize      # 100663296
